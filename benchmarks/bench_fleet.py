@@ -7,8 +7,8 @@ workers, and reports cells/hour for both plus the speedup.  The grid is
 packed into single-cell batches (``max_envs == lanes``) so the deal
 stays balanced at any worker count.
 
-A warmup fleet populates the shared persistent compile cache first
-(``repro.launch.fleet`` points every worker at it), so both timed runs
+A warmup fleet populates the checkout's persistent compile cache first
+(``repro.launch.compile_cache``; every worker uses it), so both timed runs
 measure steady-state search throughput rather than XLA compiles.  Both
 legs run with the elastic supervisor and worker lease heartbeats
 enabled (the production path), so the floor keeps those overheads
@@ -74,13 +74,11 @@ def _spec(name: str, episodes: int = EPISODES):
 
 
 def bench_rows():
-    from repro.launch.fleet import COMPILE_CACHE_ENV, run_fleet
+    from repro.launch.fleet import run_fleet
 
     spec = _spec("bench")
     n_cells = spec.n_cells
     tmp = tempfile.mkdtemp(prefix="bench_fleet_")
-    old_cache = os.environ.get(COMPILE_CACHE_ENV)
-    os.environ[COMPILE_CACHE_ENV] = os.path.join(tmp, "jax_cache")
     try:
         # warmup: one single-worker fleet at a small budget compiles the
         # (B = lanes) step + learner update once into the shared cache;
@@ -101,10 +99,6 @@ def bench_rows():
         wN_s = time.time() - t0
         assert sN.all_done(), f"W={WORKERS} fleet did not complete"
     finally:
-        if old_cache is None:
-            os.environ.pop(COMPILE_CACHE_ENV, None)
-        else:
-            os.environ[COMPILE_CACHE_ENV] = old_cache
         shutil.rmtree(tmp, ignore_errors=True)
 
     def busy(store):

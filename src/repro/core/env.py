@@ -212,14 +212,13 @@ def _sharded_step_fns(mesh):
     replicated operand.  Cached per mesh so every env on the same mesh
     shares one compiled step.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     pb = P(mesh.axis_names[0])
     rep = P()
 
     def sm(fn, in_specs):
-        return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=pb))
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                     out_specs=pb))
 
     step_analytic = sm(_step_analytic_fn,
                        (pb, pb, pb, rep, pb, pb, pb))

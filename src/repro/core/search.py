@@ -372,13 +372,13 @@ def run_search_cells(workload: Workload, node_nms: Sequence[int], *,
     t0 = time.time()
     env = VecDSEEnv(workload, np.repeat(node_nms, lanes).tolist(),
                     high_perf=high_perf, seed=sc.seed, devices=devices)
-    # Pallas hot-path kernels (TPU backends, or REPRO_PALLAS=1 to force the
-    # interpret path): actor sampling + surrogate K-candidate screening run
-    # through repro.kernels; the default CPU path stays the jnp reference.
-    _policy_act = (kernel_ops.policy_act_batch if kernel_ops.kernels_enabled()
+    # Pallas hot-path kernels on a TPU backend: actor sampling + surrogate
+    # K-candidate screening run through repro.kernels; the CPU runs the jnp
+    # reference.
+    on_tpu = kernel_ops.kernels_enabled()
+    _policy_act = (kernel_ops.policy_act_batch if on_tpu
                    else sac_mod.policy_act_batch)
-    _screen = (kernel_ops.screen_batch if kernel_ops.kernels_enabled()
-               else sur_mod.screen_batch)
+    _screen = kernel_ops.screen_batch if on_tpu else sur_mod.screen_batch
     rng = np.random.default_rng(sc.seed)
     key = jax.random.PRNGKey(sc.seed)
 

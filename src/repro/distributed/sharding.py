@@ -205,16 +205,26 @@ def batch_mesh(devices: Optional[int] = None, *,
     requested than ``jax.device_count()`` provides — CLI layers should
     surface that before any compile (see ``repro.launch.dse``).
     """
-    avail = jax.device_count()
-    n = avail if devices is None else int(devices)
+    n = jax.device_count() if devices is None else int(devices)
     if n < 1:
         raise ValueError(f"batch_mesh needs >= 1 device (got {n})")
-    if n > avail:
-        raise ValueError(f"batch_mesh: {n} devices requested but only "
-                         f"{avail} visible (jax.device_count()); emulate "
-                         "host devices with XLA_FLAGS="
-                         "--xla_force_host_platform_device_count=N")
+    check_visible_devices(n)
     return Mesh(np.asarray(jax.devices()[:n]), (axis,))
+
+
+def check_visible_devices(n: int) -> None:
+    """Raise ``ValueError`` unless this process sees at least ``n``
+    devices.  The message names the platform and the count found; only on
+    the CPU does it suggest emulating more devices with ``XLA_FLAGS``."""
+    devs = jax.devices()
+    if n <= len(devs):
+        return
+    platform = devs[0].platform
+    hint = (f"; emulate host devices with XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n}"
+            if platform == "cpu" else "")
+    raise ValueError(f"{n} devices requested but only {len(devs)} "
+                     f"{platform} device(s) visible{hint}")
 
 
 def shard_keys(key: jax.Array, n_shards: int) -> jax.Array:

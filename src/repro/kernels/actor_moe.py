@@ -18,8 +18,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_B = 256
 LOG_STD_MIN, LOG_STD_MAX = -20.0, 2.0   # networks.py clamp (Eq. 5)
@@ -56,7 +55,7 @@ def _actor_kernel(s_ref, gw_ref, l1w_ref, l1b_ref, l2w_ref, l2b_ref,
 def actor_forward_pallas(s: jnp.ndarray, gate_w, l1w, l1b, l2w, l2b,
                          dw, db, mw, mb, lw, lb, *,
                          block_b: int = DEFAULT_BLOCK_B,
-                         interpret: bool = True):
+                         interpret: bool = False):
     """s: [B, S]; gate_w: [S, K]; l*/d*/m*/lw/lb: stacked per-expert dense
     params [K, ...].  Returns (disc_logits [B, n_disc_out], mu [B, n_cont],
     log_std [B, n_cont], gate [B, K]) — the flat-head view of
@@ -80,7 +79,7 @@ def actor_forward_pallas(s: jnp.ndarray, gate_w, l1w, l1b, l2w, l2b,
         out_specs=[blk(n_disc), blk(n_cont), blk(n_cont), blk(n_exp)],
         out_shape=[jax.ShapeDtypeStruct((Bp, d), jnp.float32)
                    for d in (n_disc, n_cont, n_cont, n_exp)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(s, gate_w, l1w, l1b, l2w, l2b, dw, db, mw, mb, lw, lb)

@@ -7,9 +7,9 @@ the innermost (sequential) grid axis, with f32 VMEM scratch accumulators
 steps.  block_q = block_k = 128 matches the MXU tile; K/V blocks for GQA are
 indexed h -> h * Hk // H so each query-head group reads its shared KV head.
 
-This container is CPU-only: `ops.flash_attention` runs the kernel with
-``interpret=True`` (Python emulation) and tests assert allclose against
-``ref.attention_reference``.  On TPU the same pallas_call lowers natively.
+On TPU the pallas_call lowers natively; the CPU tests run it with
+``interpret=True`` (Python emulation) and assert allclose against
+``ref.attention_reference``.
 """
 from __future__ import annotations
 
@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels import tpu_compiler_params
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -76,7 +74,7 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                            causal: bool = True, window: int = 0,
                            block_q: int = DEFAULT_BLOCK_Q,
                            block_k: int = DEFAULT_BLOCK_K,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool = False) -> jnp.ndarray:
     """q: [B, H, Sq, hd]; k/v: [B, Hk, Sk, hd].  Returns [B, H, Sq, hd]."""
     B, H, Sq, hd = q.shape
     Hk, Sk = k.shape[1], k.shape[2]
@@ -109,7 +107,7 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 DEFAULT_BLOCK_B = 256
 
 
@@ -45,7 +43,7 @@ def _mlp_kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, w3_ref, b3_ref,
 
 def fused_mlp_pallas(x: jnp.ndarray, w1, b1, w2, b2, w3, b3, *,
                      block_b: int = DEFAULT_BLOCK_B,
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: bool = False) -> jnp.ndarray:
     """x: [B, d_in]; weights wi: [d_{i-1}, d_i], bi: [d_i].  Pads B to the
     batch tile.  Returns [B, d_out] in x.dtype."""
     B, d_in = x.shape
@@ -66,7 +64,7 @@ def fused_mlp_pallas(x: jnp.ndarray, w1, b1, w2, b2, w3, b3, *,
         ],
         out_specs=pl.BlockSpec((block_b, d_out), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, d_out), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, w1, b1, w2, b2, w3, b3)

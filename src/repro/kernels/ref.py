@@ -85,18 +85,3 @@ def actor_forward_reference(params, s: jnp.ndarray):
     from repro.core.networks import actor_forward
     return actor_forward(params, s)
 
-
-def sumtree_set_many_reference(tree, idx, values):
-    """Host float64 SumTree oracle: replays ``set_many`` on a live
-    ``repro.core.replay.SumTree`` seeded from ``tree`` and returns the
-    updated [2 * capacity] array."""
-    import numpy as np
-
-    from repro.core.replay import SumTree
-    tree = np.asarray(tree, np.float64)
-    st = SumTree(tree.shape[0] // 2)
-    st.tree[:] = tree
-    st.set_many(np.asarray(idx, np.int64),
-                np.broadcast_to(np.asarray(values, np.float64),
-                                np.asarray(idx).shape))
-    return st.tree.copy()

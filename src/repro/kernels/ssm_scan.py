@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 DEFAULT_BLOCK_D = 512
 DEFAULT_CHUNK = 128
 
@@ -53,7 +51,7 @@ def ssm_scan_pallas(dt: jnp.ndarray, b_in: jnp.ndarray, c_in: jnp.ndarray,
                     x: jnp.ndarray, a: jnp.ndarray, *,
                     block_d: int = DEFAULT_BLOCK_D,
                     chunk: int = DEFAULT_CHUNK,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool = False) -> jnp.ndarray:
     """dt/x: [B,S,D]; b_in/c_in: [B,S,N]; a: [D,N] -> y [B,S,D] (f32)."""
     B, S, D = x.shape
     N = a.shape[1]
@@ -76,7 +74,7 @@ def ssm_scan_pallas(dt: jnp.ndarray, b_in: jnp.ndarray, c_in: jnp.ndarray,
         out_specs=pl.BlockSpec((1, chunk, block_d), lambda b, d, c: (b, c, d)),
         out_shape=jax.ShapeDtypeStruct((B, S, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_d, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(dt, b_in, c_in, x, a)

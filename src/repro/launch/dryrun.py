@@ -15,7 +15,7 @@ import jax               # noqa: E402
 from repro.configs import ARCH_IDS, get_config          # noqa: E402
 from repro.launch import shapes as shp                   # noqa: E402
 from repro.launch.hlo_analysis import analyze_collectives  # noqa: E402
-from repro.launch.mesh import make_production_mesh, mesh_context  # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.steps import build_prefill, build_serve, build_train  # noqa: E402
 
 DEFAULT_OUT = "experiments/dryrun"
@@ -69,7 +69,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
     try:
         mesh = make_production_mesh(multi_pod=multi_pod)
         kind = shp.SHAPES[shape]["kind"]
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             if kind == "train":
                 fn, sds, in_sh, out_sh = build_train(cfg, mesh, shape)
                 state_sds, batch_sds = sds

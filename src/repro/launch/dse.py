@@ -31,6 +31,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.search import (SearchConfig, SearchResult, run_grid,
                                run_random, run_sac, run_search)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.ppa.analytic import M_IDX
 from repro.ppa.nodes import NODES
 from repro.workload.extract import DTYPES, PHASES, extract
@@ -110,6 +111,11 @@ def run(arch: str, *, nodes: List[int], mode: str, episodes: int,
     return rows
 
 
+def _read_manifest(root: str) -> Dict:
+    with open(os.path.join(root, "manifest.json")) as f:
+        return json.load(f)
+
+
 def _parse_hosts(s: Optional[str]) -> Optional[List[str]]:
     """--hosts comma list -> cleaned host names (None if flag absent)."""
     if s is None:
@@ -117,35 +123,42 @@ def _parse_hosts(s: Optional[str]) -> Optional[List[str]]:
     return [h.strip() for h in s.split(",") if h.strip()]
 
 
-def _resolve_devices(ap: argparse.ArgumentParser,
-                     a: argparse.Namespace) -> Optional[int]:
+def _resolve_devices(ap: argparse.ArgumentParser, a: argparse.Namespace,
+                     *, fleet: bool) -> Optional[int]:
     """--mesh/--devices -> mesh device count (None = plain jit).
 
-    Validated against the *visible* JAX device set here, before anything
-    traces or compiles: a count larger than ``jax.device_count()`` dies
-    with a one-line ``ap.error`` instead of a shard_map traceback deep in
-    the engine.  ``--mesh auto`` takes every visible device.
+    A single-process run validates against the *visible* JAX device set
+    here, before anything traces or compiles: a count larger than
+    ``jax.device_count()`` dies with a one-line ``ap.error`` instead of a
+    shard_map traceback deep in the engine, and ``--mesh auto`` takes
+    every visible device.  A fleet parent never asks JAX for devices (a
+    chip serves one process, and the workers need it): each worker checks
+    the count it sees when it starts.
     """
     if a.mesh is not None and a.devices is not None:
         ap.error("--mesh and --devices are aliases; pass exactly one")
     spec = a.mesh if a.mesh is not None else a.devices
     if spec is None:
         return None
-    import jax  # lazy: only mesh runs pay backend init at arg-parse time
-    avail = jax.device_count()
     if spec == "auto":
-        return avail
+        if fleet:
+            ap.error("--mesh auto counts this process's devices, and a "
+                     "fleet parent leaves them to its workers; pass "
+                     "--devices N with --workers")
+        import jax
+        return jax.device_count()
     try:
         n = int(spec)
     except ValueError:
         ap.error(f"--mesh must be 'auto' or a device count (got {spec!r})")
     if n < 1:
         ap.error(f"--devices must be >= 1 (got {n})")
-    if n > avail:
-        ap.error(
-            f"--devices {n}: only {avail} JAX device(s) visible; emulate "
-            "host devices with "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={n}")
+    if not fleet:
+        from repro.distributed.sharding import check_visible_devices
+        try:
+            check_visible_devices(n)
+        except ValueError as e:
+            ap.error(f"--devices {n}: {e}")
     return n
 
 
@@ -339,7 +352,13 @@ def main(argv: Optional[List[str]] = None) -> None:
     ap.add_argument("--verbose", action="store_true")
     a = ap.parse_args(argv)
     validate_args(ap, a)
-    devices = _resolve_devices(ap, a)
+    enable_compile_cache()
+    fleet = a.workers is not None or bool(
+        a.resume and _read_manifest(a.resume).get("fleet"))
+    if fleet:
+        from repro.launch.fleet import keep_off_chip
+        keep_off_chip()
+    devices = _resolve_devices(ap, a, fleet=fleet)
     if devices is not None and not a.campaign and a.n_envs % devices:
         ap.error(f"--n-envs {a.n_envs} must divide evenly over "
                  f"--devices {devices}")
@@ -353,9 +372,7 @@ def main(argv: Optional[List[str]] = None) -> None:
             from repro.launch.fleet import make_launcher
             fleet_kw["launcher"] = make_launcher(a.launch_template, hosts)
         if a.resume:
-            with open(os.path.join(a.resume, "manifest.json")) as f:
-                manifest = json.load(f)
-            if a.workers is not None or manifest.get("fleet"):
+            if fleet:
                 from repro.launch.fleet import run_fleet
                 run_fleet(a.resume, workers=a.workers, resume=True,
                           **fleet_kw)

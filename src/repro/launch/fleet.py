@@ -35,10 +35,15 @@ re-deals, but it still polls with a timeout instead of blocking
 sequentially and reconciles each worker's results as soon as that worker
 exits.
 
-Workers share a persistent XLA compile cache (env
-``REPRO_FLEET_COMPILE_CACHE``, default ``<run-dir>/.jax_cache``; set it
-to an empty string to disable) so W processes pay for one compile of the
+Workers share the checkout's persistent XLA compile cache
+(``repro.launch.compile_cache``) so W processes pay for one compile of the
 shared search step, not W.
+
+**One process per chip**: a chip belongs to one process at a time, so the
+fleet parent never initializes an accelerator backend — what JAX work it
+does (a ``--transfer-from`` cost-model fit, reconcile) runs on the host
+CPU — and a local fleet whose workers would share one chip is refused
+before any worker is spawned.
 
 Workers only ever touch the shared run directory, so the same layout
 shards across hosts over a shared filesystem: the command-template
@@ -68,9 +73,8 @@ import sys
 import time
 from typing import Dict, List, Optional
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import trace as obs_trace
-
-COMPILE_CACHE_ENV = "REPRO_FLEET_COMPILE_CACHE"
 
 #: default remote template; ``{python}`` resolves to the LOCAL
 #: interpreter path and is usually wrong across hosts — the default
@@ -85,22 +89,38 @@ class FleetError(RuntimeError):
     --resume to re-deal the unfinished batches)."""
 
 
-def enable_compile_cache(path: str) -> None:
-    """Point jax's persistent compile cache at ``path`` (best-effort: an
-    older jax without the knobs just compiles per-process)."""
+def keep_off_chip() -> None:
+    """Keep this fleet parent off the accelerator its workers need.
+
+    Before any JAX backend exists, pin the process to the host CPU, so
+    whatever JAX work the parent does never claims the chip.  A process
+    that already holds an accelerator cannot hand it to local workers."""
     import jax
-    for key, val in (("jax_compilation_cache_dir", path),
-                     ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                     ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(key, val)
-        except Exception:
-            pass
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        jax.config.update("jax_platforms", "cpu")
+    elif jax.default_backend() != "cpu":
+        raise FleetError(
+            f"this process already holds the {jax.default_backend()} "
+            "backend, and a chip belongs to one process at a time: launch "
+            "the fleet from a process that has not used it")
 
 
-def _worker_env(root: str) -> Dict[str, str]:
-    """Child env: repro importable + shared compile cache under the run
-    dir unless the caller already pinned one."""
+def _worker_platform(env: Dict[str, str]) -> str:
+    """The JAX platform a worker spawned with ``env`` gets.  Workers
+    pinned to the CPU need no probe; otherwise a short-lived child asks
+    JAX and exits, releasing whatever it claimed before any worker
+    starts."""
+    if env.get("JAX_PLATFORMS", "").split(",")[0].strip() == "cpu":
+        return "cpu"
+    out = subprocess.run(
+        [sys.executable, "-c", "import jax; print(jax.default_backend())"],
+        env=env, capture_output=True, text=True, check=True)
+    return out.stdout.split()[-1]
+
+
+def _worker_env() -> Dict[str, str]:
+    """Child env: the parent's, with repro importable."""
     import repro
     env = dict(os.environ)
     # __path__ (not __file__): repro is a namespace package without its
@@ -109,8 +129,6 @@ def _worker_env(root: str) -> Dict[str, str]:
     parts = [src] + [p for p in env.get("PYTHONPATH", "").split(os.pathsep)
                      if p and p != src]
     env["PYTHONPATH"] = os.pathsep.join(parts)
-    env.setdefault(COMPILE_CACHE_ENV,
-                   os.path.join(os.path.abspath(root), ".jax_cache"))
     return env
 
 
@@ -411,7 +429,7 @@ class FleetHandle:
                         f"[fleet] worker {idx} down ({reason}); re-dealt "
                         f"{len(todo)} batch(es) to fresh slot {new_idx}")
                     wp = self.launcher.spawn(self.root, new_idx,
-                                             _worker_env(self.root))
+                                             _worker_env())
                     obs_trace.instant("worker_spawned", cat="fleet",
                                       worker=new_idx)
                     live[new_idx] = self.procs[new_idx] = wp
@@ -560,6 +578,7 @@ def launch_fleet(root: str, spec=None, *, workers: Optional[int] = None,
         raise ValueError(f"workers must be >= 1 (got {workers})")
     if lease_ttl_s is not None and lease_ttl_s <= 0:
         raise ValueError(f"lease_ttl_s must be > 0 (got {lease_ttl_s})")
+    keep_off_chip()
     if resume:
         store = distrib.plan_resume(root, workers,
                                     lease_ttl_s=lease_ttl_s)
@@ -590,9 +609,18 @@ def launch_fleet(root: str, spec=None, *, workers: Optional[int] = None,
         tracer = obs_trace.Tracer(
             os.path.join(root, obs_trace.TRACE_NAME), proc="fleet")
         obs_trace.install_tracer(tracer)
-    env = _worker_env(root)
+    env = _worker_env()
+    slots = sorted(set(assignments.values()))
+    if isinstance(launcher, LocalLauncher) and len(slots) > 1:
+        platform = _worker_platform(env)
+        if platform != "cpu":
+            raise FleetError(
+                f"{len(slots)} local workers would share this host's "
+                f"{platform} chip, which serves one process at a time; "
+                f"resume with one worker (--resume {root} --workers 1) or "
+                "run the campaign without --workers")
     procs: Dict[int, WorkerProc] = {}
-    for idx in sorted(set(assignments.values())):
+    for idx in slots:
         procs[idx] = launcher.spawn(root, idx, env)
         obs_trace.instant("worker_spawned", cat="fleet", worker=idx)
     n_batches = len(assignments)
@@ -650,12 +678,12 @@ def main(argv: Optional[List[str]] = None) -> None:
         print(json.dumps(status, indent=2) if a.json
               else render_status(status))
         return
-    # validate on the raw manifest: importing repro.campaign here would
-    # pull in jax BEFORE enable_compile_cache below, and jax's persistent
-    # compile cache silently stays off if it initializes first — every
-    # worker would then pay a full recompile (measured ~2x batch time)
+    # validate on the raw manifest, before anything compiles: the shared
+    # compile cache must be on before the first compile, or every worker
+    # pays a full recompile (measured ~2x batch time)
     with open(manifest_path) as f:
-        fleet = json.load(f).get("fleet")
+        manifest = json.load(f)
+    fleet = manifest.get("fleet")
     if not fleet:
         ap.error(f"--root {a.root} is not a fleet campaign (no fleet "
                  "block in manifest.json); launch it with --workers "
@@ -667,14 +695,15 @@ def main(argv: Optional[List[str]] = None) -> None:
         ap.error(f"--worker {a.worker} has no batches in the recorded "
                  f"deal ({desc}); re-deal with repro.launch.dse "
                  "--resume --workers N")
-    cache = os.environ.get(COMPILE_CACHE_ENV)
-    if cache is None:
-        # default matches the parent launcher, so a bare (multi-host)
-        # worker invocation shares the fleet's compile cache too; set
-        # the env var to an empty string to disable
-        cache = os.path.join(os.path.abspath(a.root), ".jax_cache")
-    if cache:
-        enable_compile_cache(cache)
+    enable_compile_cache()
+    devices = (manifest.get("spec") or {}).get("devices")
+    if devices:
+        from repro.distributed.sharding import check_visible_devices
+        try:
+            check_visible_devices(int(devices))
+        except ValueError as e:
+            ap.error(f"--worker {a.worker}: the campaign spec shards over "
+                     f"devices={devices}: {e}")
     from repro.campaign.distrib import run_worker
     run_worker(a.root, a.worker)
 

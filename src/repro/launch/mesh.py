@@ -8,37 +8,15 @@ import; everything else sees the real device count.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: meshes are implicitly Auto
-    AxisType = None
-
-
-def mesh_context(mesh: Mesh):
-    """Context manager making ``mesh`` the ambient mesh, across jax versions.
-
-    jax >= 0.6 uses ``jax.set_mesh(mesh)``; on jax 0.4.x the ``Mesh`` object
-    itself is the context manager (legacy global resource env).
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def _axis_types_kw(n_axes: int) -> dict:
-    """make_mesh kwargs for explicit Auto axis types, when supported."""
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """16x16 (256-chip pod) or 2x16x16 (2 pods, 512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(n_data: int = 2, n_model: int = 2) -> Mesh:
@@ -47,7 +25,7 @@ def make_test_mesh(n_data: int = 2, n_model: int = 2) -> Mesh:
     n_data = min(n_data, n)
     n_model = max(1, min(n_model, n // n_data))
     return jax.make_mesh((n_data, n_model), ("data", "model"),
-                         **_axis_types_kw(2))
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def dp_axes(mesh: Mesh):

@@ -48,6 +48,7 @@ from repro.campaign.planner import (DEFAULT_DTYPE, DEFAULT_PHASE, MODES,
 from repro.campaign.store import CampaignStore
 from repro.configs import ARCH_IDS, get_config
 from repro.core.pareto import ArchiveEntry, ParetoArchive
+from repro.launch.compile_cache import enable_compile_cache
 from repro.ppa import config_space as cs
 from repro.ppa import surrogate as sur_mod
 from repro.ppa.analytic import NODE_DIM, node_vector
@@ -500,6 +501,7 @@ def main(argv: Optional[List[str]] = None) -> None:
     if not a.serve and not a.batch and a.node is None:
         ap.error("--node is required (unless --serve or --batch carries "
                  "per-query nodes)")
+    enable_compile_cache()
     if a.serve:
         from repro.launch.serve import recommend_server
         recommend_server(a.root, host=a.host, port=a.port)

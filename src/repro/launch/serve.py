@@ -15,7 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_reduced
-from repro.launch.mesh import make_test_mesh, mesh_context
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_test_mesh
 from repro.models import layers as L
 from repro.models import lm
 from repro.models.blocks import KV_TAIL
@@ -30,7 +31,7 @@ def serve(arch: str, *, reduced: bool = True, batch: int = 4,
     # correlates weights with inputs (and makes the three draws identical
     # noise up to shape), which skews any numerics derived from them
     k_params, k_prompts, k_ctx = jax.random.split(jax.random.PRNGKey(seed), 3)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params = lm.init_params(k_params, cfg)
         cache_len = prompt_len + gen_tokens
         prompts = jax.random.randint(k_prompts, (batch, prompt_len), 0,
@@ -249,6 +250,7 @@ def main() -> None:
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8177)
     a = ap.parse_args()
+    enable_compile_cache()
     if a.recommend:
         recommend_server(a.recommend, host=a.host, port=a.port)
         return

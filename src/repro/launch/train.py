@@ -18,7 +18,7 @@ import numpy as np
 from repro.checkpoint import manager as ckpt
 from repro.configs import get_config, get_reduced
 from repro.data.pipeline import DataConfig, batch_at
-from repro.launch.mesh import dp_axes, make_test_mesh, mesh_context
+from repro.launch.mesh import dp_axes, make_test_mesh
 from repro.distributed import sharding as sh
 from repro.models import lm
 from repro.optim.trainer import TrainConfig, create_state, make_train_step
@@ -38,7 +38,7 @@ def train(arch: str, *, reduced: bool = True, steps: int = 100,
                     global_batch=global_batch, seed=seed)
 
     key = jax.random.PRNGKey(seed)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params = lm.init_params(key, cfg)
         p_sh = sh.param_shardings(params, mesh, fsdp="data", tp="model")
         params = jax.device_put(params, p_sh)

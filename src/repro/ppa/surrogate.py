@@ -24,7 +24,7 @@ SUR_HIDDEN = (128, 64)
 N_TARGETS = 3  # power, perf, area  (Eq. 61)
 TARGET_NAMES = ("power_mw", "perf_gops", "area_mm2")
 # log1p-scaled targets; weights w_q of Eq. 65
-TARGET_WEIGHTS = jnp.array([1.0, 1.0, 1.0])
+TARGET_WEIGHTS = np.array([1.0, 1.0, 1.0], np.float32)
 TAU_SUR_DEFAULT = 0.05
 
 

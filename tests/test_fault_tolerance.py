@@ -100,7 +100,6 @@ def test_compression_error_feedback_converges():
 def test_compressed_psum_shard_map():
     if len(jax.devices()) < 1:
         pytest.skip("no devices")
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
     g = dict(w=jnp.ones((8,), jnp.float32) * 3.0)
@@ -109,6 +108,6 @@ def test_compressed_psum_shard_map():
     def f(g, r):
         return compressed_psum(g, r, "dp")
 
-    out, new_r = shard_map(f, mesh=mesh, in_specs=(P(), P()),
-                           out_specs=(P(), P()))(g, r)
+    out, new_r = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                               out_specs=(P(), P()))(g, r)
     np.testing.assert_allclose(np.asarray(out["w"]), 3.0, atol=0.05)

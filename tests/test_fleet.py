@@ -7,6 +7,9 @@ import glob
 import json
 import os
 import signal
+import subprocess
+import sys
+import textwrap
 import time
 
 import numpy as np
@@ -21,6 +24,7 @@ from repro.campaign.store import STATUS_DONE
 from repro.core.pareto import ArchiveEntry
 from repro.launch import dse
 from repro.launch import fleet as fleet_mod
+from repro.launch.compile_cache import CACHE_DIR_ENV, enable_compile_cache
 
 ARCH = "smollm-135m"
 GRID = os.path.join(os.path.dirname(__file__), os.pardir,
@@ -38,16 +42,12 @@ def smoke_spec(name, **kw):
 def fleet_cache(tmp_path_factory):
     """Session-shared persistent compile cache: the first search pays the
     XLA compile, every later in-process run and worker subprocess reuses
-    it (workers inherit it via REPRO_FLEET_COMPILE_CACHE)."""
+    it (workers inherit JAX_COMPILATION_CACHE_DIR)."""
     cache = str(tmp_path_factory.mktemp("jax_compile_cache"))
-    fleet_mod.enable_compile_cache(cache)
-    old = os.environ.get(fleet_mod.COMPILE_CACHE_ENV)
-    os.environ[fleet_mod.COMPILE_CACHE_ENV] = cache
-    yield cache
-    if old is None:
-        os.environ.pop(fleet_mod.COMPILE_CACHE_ENV, None)
-    else:
-        os.environ[fleet_mod.COMPILE_CACHE_ENV] = old
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv(CACHE_DIR_ENV, cache)
+        assert enable_compile_cache() == cache
+        yield cache
 
 
 # ---------------------------------------------------------------- sharding
@@ -315,3 +315,42 @@ def test_fleet_warm_start_w2_matches_w1_bitwise(tmp_path, fleet_cache):
             assert CampaignStore.open(wr).manifest["transfer"] == top
             mirrored += 1
     assert mirrored == 2
+
+
+# ------------------------------------------------------ one process per chip
+def test_fleet_parent_initializes_no_jax_backend(tmp_path):
+    """The fleet parent must not claim the chip its workers need: run the
+    CLI's fleet path in a fresh process and look at JAX's backends at the
+    moment the first worker would be spawned."""
+    script = textwrap.dedent(f"""
+        import json, sys
+        from jax._src import xla_bridge
+        from repro.launch import dse, fleet
+
+        def spawn(self, root, idx, env=None):
+            print(json.dumps(dict(
+                initialized=xla_bridge.backends_are_initialized())))
+            sys.exit(0)
+
+        fleet.LocalLauncher.spawn = spawn
+        dse.main(["--campaign", {GRID!r}, "--workers", "2",
+                  "--campaign-root", {str(tmp_path)!r}])
+        """)
+    out = subprocess.run([sys.executable, "-c", script],
+                         env=fleet_mod._worker_env(), capture_output=True,
+                         text=True, timeout=300)
+    assert out.returncode == 0, out.stderr
+    assert json.loads(out.stdout.splitlines()[-1]) == {"initialized": False}
+
+
+def test_local_fleet_refuses_to_share_one_chip(tmp_path, monkeypatch):
+    """W > 1 local workers on an accelerator host would contend for one
+    chip: the launch fails before any worker is spawned."""
+    spawned = []
+    monkeypatch.setattr(fleet_mod, "_worker_platform", lambda env: "tpu")
+    monkeypatch.setattr(fleet_mod.LocalLauncher, "spawn",
+                        lambda self, *a, **k: spawned.append(a))
+    with pytest.raises(fleet_mod.FleetError, match="share this host's tpu"):
+        fleet_mod.launch_fleet(str(tmp_path / "f"), smoke_spec("chip"),
+                               workers=2, progress=_silent)
+    assert spawned == []

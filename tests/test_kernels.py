@@ -24,7 +24,7 @@ def test_flash_attention_sweep(B, H, Hk, S, hd, causal, window, dtype, tol):
     q, k, v = (_mk((B, H, S, hd), dtype), _mk((B, Hk, S, hd), dtype),
                _mk((B, Hk, S, hd), dtype))
     out = ops.flash_attention(q, k, v, causal=causal, window=window,
-                              block_q=64, block_k=64)
+                              block_q=64, block_k=64, interpret=True)
     want = ref.attention_reference(q, k, v, causal=causal, window=window)
     err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
                                 - want.astype(jnp.float32))))
@@ -42,7 +42,8 @@ def test_ssm_scan_sweep(B, S, D, N, bd, ch):
     c_in = _mk((B, S, N), jnp.float32)
     x = _mk((B, S, D), jnp.float32)
     a = -jnp.exp(_mk((D, N), jnp.float32) * 0.5)
-    y = ops.ssm_scan(dt, b_in, c_in, x, a, block_d=bd, chunk=ch)
+    y = ops.ssm_scan(dt, b_in, c_in, x, a, block_d=bd, chunk=ch,
+                     interpret=True)
     want, _ = ref.ssm_scan_reference(dt, b_in, c_in, x, a)
     np.testing.assert_allclose(np.asarray(y), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
@@ -56,7 +57,7 @@ def test_fused_mlp_sweep(B, block, dtype, tol):
     ws = [_mk((82, 128), jnp.float32) * 0.1, jnp.zeros(128),
           _mk((128, 64), jnp.float32) * 0.1, jnp.zeros(64),
           _mk((64, 52), jnp.float32) * 0.1, jnp.zeros(52)]
-    y = ops.fused_mlp(x, *ws, block_b=block)
+    y = ops.fused_mlp(x, *ws, block_b=block, interpret=True)
     want = ref.fused_mlp_reference(x, *ws)
     err = float(jnp.max(jnp.abs(y.astype(jnp.float32)
                                 - want.astype(jnp.float32))))
@@ -64,7 +65,7 @@ def test_fused_mlp_sweep(B, block, dtype, tol):
 
 
 # ------------------------------------------------------------------------
-# DSE search-loop kernels (screen / MoE actor / PER sum-tree)
+# DSE search-loop kernels (screen / MoE actor)
 # ------------------------------------------------------------------------
 
 @pytest.mark.parametrize("B,K,block", [(8, 4, 8), (33, 6, 16), (128, 4, 64)])
@@ -79,14 +80,15 @@ def test_screen_scores_sweep(B, K, block):
     s = _mk((B, SAC_STATE_DIM), jnp.float32)
     cand = _mk((B, K, N_CONT), jnp.float32)
     w = jnp.asarray(RNG.dirichlet(np.ones(3), B), jnp.float32)
-    score = ops.screen_scores(params, s, cand, w, block_b=block)
+    score = ops.screen_scores(params, s, cand, w, block_b=block,
+                              interpret=True)
     want = ref.screen_scores_reference(params, s, cand, w)
     np.testing.assert_allclose(np.asarray(score), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
     # full drop-in: same pick as the live surrogate screen on random
     # (well-separated) scores, gate open and closed
     mask = jnp.asarray(RNG.random(B) < 0.5)
-    pick_k = ops.screen_batch(params, s, cand, w, mask)
+    pick_k = ops.screen_batch(params, s, cand, w, mask, interpret=True)
     pick_r = screen_batch(params, s, cand, w, mask)
     assert bool(jnp.all(pick_k == pick_r))
 
@@ -100,7 +102,7 @@ def test_actor_forward_parity(B):
 
     params = nets.actor_init(jax.random.PRNGKey(3))
     s = _mk((B, SAC_STATE_DIM), jnp.float32)
-    got = ops.actor_forward(params, s)
+    got = ops.actor_forward(params, s, interpret=True)
     want = ref.actor_forward_reference(params, s)
     for g, w in zip(got, want):
         assert g.shape == w.shape
@@ -118,35 +120,13 @@ def test_policy_act_batch_parity():
     params = nets.actor_init(jax.random.PRNGKey(4))
     s = _mk((64, SAC_STATE_DIM), jnp.float32)
     key = jax.random.PRNGKey(7)
-    a_k, ad_k = ops.policy_act_batch(params, s, key)
+    a_k, ad_k = ops.policy_act_batch(params, s, key, interpret=True)
     a_r, ad_r = sac_mod.policy_act_batch(params, s, key)
     np.testing.assert_allclose(np.asarray(a_k), np.asarray(a_r),
                                rtol=1e-4, atol=1e-5)
     # categorical sampling sees float-eps logit differences; ties are
     # measure-zero on random logits but tolerate a stray flip
     assert float(jnp.mean(ad_k == ad_r)) >= 0.99
-
-
-@pytest.mark.parametrize("cap", [8, 100, 257])
-def test_sumtree_set_many_parity(cap):
-    from repro.core.replay import SumTree
-
-    st = SumTree(cap)
-    st.set_many(np.arange(cap), RNG.random(cap))
-    n = min(37, cap)
-    idx = RNG.integers(0, cap, n)            # duplicates: last write wins
-    vals = RNG.random(n)
-    got = np.asarray(ops.sumtree_set_many(
-        jnp.asarray(st.tree, jnp.float32), idx, vals))
-    want = ref.sumtree_set_many_reference(st.tree, idx, vals)
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
-    # scalar priority broadcast (the PERBuffer.add_batch insert path)
-    got_s = np.asarray(ops.sumtree_set_many(
-        jnp.asarray(st.tree, jnp.float32), idx, 0.5))
-    want_s = ref.sumtree_set_many_reference(st.tree, idx, 0.5)
-    np.testing.assert_allclose(got_s, want_s, rtol=1e-4, atol=1e-4)
-    # root == sum of leaves
-    np.testing.assert_allclose(got[1], got[cap:].sum(), rtol=1e-4)
 
 
 def test_chunked_attention_matches_kernel_layout():
@@ -163,3 +143,43 @@ def test_chunked_attention_matches_kernel_layout():
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(want.transpose(0, 2, 1, 3)),
                                rtol=2e-4, atol=2e-4)
+
+
+def test_kernels_enabled_follows_the_platform(monkeypatch):
+    import jax
+
+    assert ops.kernels_enabled() is (jax.default_backend() == "tpu")
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu' backend"):
+        ops.kernels_enabled()
+
+
+def test_search_loop_runs_through_the_kernels(monkeypatch):
+    """The path a TPU takes: the vec search loop with the actor and the
+    screening kernels in place of the jnp reference (interpreted here),
+    gate open, to the end of its budget."""
+    from repro.configs import get_config
+    from repro.core.search import SearchConfig, run_search_cells
+    from repro.workload.extract import extract
+
+    calls = {"act": 0, "screen": 0}
+
+    def interpreted(name, fn):
+        def call(*a, **k):
+            calls[name] += 1
+            return fn(*a, interpret=True, **k)
+        return call
+
+    monkeypatch.setattr(ops, "kernels_enabled", lambda: True)
+    monkeypatch.setattr(ops, "policy_act_batch",
+                        interpreted("act", ops.policy_act_batch))
+    monkeypatch.setattr(ops, "screen_batch",
+                        interpreted("screen", ops.screen_batch))
+    wl = extract(get_config("smollm-135m"), seq_len=256, batch=1)
+    sc = SearchConfig(episodes=48, warmup=16, batch_size=16, seed=0,
+                      gate_threshold=1e9)
+    (res,) = run_search_cells(wl, [7], search=sc, lanes_per_cell=8)
+    assert res.episodes_run == 48
+    assert res.gate_open_episode is not None
+    assert res.screened > res.evaluated
+    assert calls["act"] > 0 and calls["screen"] > 0

@@ -120,12 +120,11 @@ def test_search_cells_bitwise_vs_single_device(wl, n_dev):
 
 # ------------------------------------------------ kernel interpret modes --
 def test_kernel_interpret_paths_match_references():
-    """The three search-loop Pallas kernels execute (interpret mode) and
+    """The two search-loop Pallas kernels execute (interpret mode) and
     match their jnp/host references — the cheap cross-check the dedicated
     ``tests/test_kernels.py`` sweeps expand on."""
     from repro.core import networks as nets
     from repro.core import sac as sac_mod
-    from repro.core.replay import SumTree
     from repro.core.state import SAC_STATE_DIM
     from repro.kernels import ops, ref
     from repro.ppa import surrogate as sur_mod
@@ -139,22 +138,15 @@ def test_kernel_interpret_paths_match_references():
     cand = jnp.asarray(rng.normal(0, 1, (B, K, N_CONT)), jnp.float32)
     w = jnp.asarray(rng.dirichlet(np.ones(3), B), jnp.float32)
     np.testing.assert_allclose(
-        np.asarray(ops.screen_scores(sp, s, cand, w)),
+        np.asarray(ops.screen_scores(sp, s, cand, w, interpret=True)),
         np.asarray(ref.screen_scores_reference(sp, s, cand, w)),
         rtol=1e-4, atol=1e-5)
 
     ap = nets.actor_init(jax.random.PRNGKey(2))
-    a_k, ad_k = ops.policy_act_batch(ap, s, jax.random.PRNGKey(3))
+    a_k, ad_k = ops.policy_act_batch(ap, s, jax.random.PRNGKey(3),
+                                     interpret=True)
     a_r, ad_r = sac_mod.policy_act_batch(ap, s, jax.random.PRNGKey(3))
     np.testing.assert_allclose(np.asarray(a_k), np.asarray(a_r),
                                rtol=1e-4, atol=1e-5)
     assert float(jnp.mean(ad_k == ad_r)) >= 0.99
 
-    st = SumTree(64)
-    st.set_many(np.arange(64), rng.random(64))
-    idx, vals = rng.integers(0, 64, 20), rng.random(20)
-    np.testing.assert_allclose(
-        np.asarray(ops.sumtree_set_many(jnp.asarray(st.tree, jnp.float32),
-                                        idx, vals)),
-        ref.sumtree_set_many_reference(st.tree, idx, vals),
-        rtol=1e-4, atol=1e-4)

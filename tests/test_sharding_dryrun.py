@@ -8,7 +8,7 @@ import pytest
 
 from repro.configs import get_reduced
 from repro.distributed import sharding as sh
-from repro.launch.mesh import make_test_mesh, mesh_context
+from repro.launch.mesh import make_test_mesh
 from repro.models import lm
 
 
@@ -64,7 +64,7 @@ def test_mini_dryrun_compiles(arch, mesh):
         batch["ctx"] = jax.ShapeDtypeStruct(
             (4, cfg.n_audio_frames, cfg.d_model), jnp.bfloat16)
     step = make_train_step(cfg, TrainConfig())
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step).lower(state_sds, batch)
         compiled = lowered.compile()
     assert compiled.cost_analysis() is not None
