@@ -249,6 +249,11 @@ def run_sac(workload: Workload, node_nm: int, *, high_perf: bool = True,
 # Vectorized engine: B environments per device dispatch (VecDSEEnv)
 # --------------------------------------------------------------------------
 
+# the phases of one dispatch of the batched loop, each timed into
+# ``search_phase_seconds{phase=...}``: the first four make up
+# ``dispatch_seconds``, the telemetry feed follows it
+PHASES = ("act", "env_step", "archive", "learn", "telemetry")
+
 _plan_batch = jax.jit(jax.vmap(mpc_mod.plan,
                                in_axes=(None, None, None, 0, 0)))
 
@@ -363,6 +368,19 @@ def run_search_cells(workload: Workload, node_nms: Sequence[int], *,
     Strictly post-loop: ``scenario=None`` is byte-identical to the
     pre-scenario engine.
     """
+    with obs_trace.phase("run_search_cells"):
+        return _search_cells(
+            workload, node_nms, high_perf=high_perf, search=search,
+            lanes_per_cell=lanes_per_cell, checkpoint_dir=checkpoint_dir,
+            checkpoint_every=checkpoint_every, resume=resume,
+            devices=devices, warm_start=warm_start,
+            save_weights_to=save_weights_to, scenario=scenario)
+
+
+def _search_cells(workload, node_nms, *, high_perf, search, lanes_per_cell,
+                  checkpoint_dir, checkpoint_every, resume, devices,
+                  warm_start, save_weights_to, scenario):
+    """:func:`run_search_cells`, inside its profiler annotation."""
     sc = search or SearchConfig()
     n_cells = len(node_nms)
     if n_cells < 1:
@@ -520,18 +538,25 @@ def run_search_cells(workload: Workload, node_nms: Sequence[int], *,
     _m_sps = _reg.gauge("env_steps_per_s")
     _m_gate = _reg.gauge("gate_open_frac")
     _m_eps = _reg.gauge("search_eps")
-    _m_ent = _reg.gauge("sac_entropy")
-    _m_prio = _reg.gauge("per_max_priority")
-    _m_size = _reg.gauge("per_size")
-    _m_beta = _reg.gauge("per_beta")
     _m_best = _reg.gauge("best_score")
     _m_disp = _reg.histogram("dispatch_seconds")
+    # act, env_step, archive and learn cover a dispatch_seconds interval;
+    # telemetry follows it, inside the dispatch's annotation
+    _m_phase = {p: _reg.histogram("search_phase_seconds",
+                                  labels={"phase": p})
+                for p in PHASES}
+    obs_trace.watch_gc()
     # screened/evaluated are cumulative in the gate (and survive resume):
     # counters track the delta per dispatch so fleet aggregation sums
     _prev_scr = float(gate.screened.sum())
     _prev_ev = float(gate.evaluated.sum())
 
     def _checkpoint(t_next: int) -> None:
+        with obs_trace.phase("checkpoint.gather"):
+            tree, extra = _checkpoint_state()
+        _save_search_ckpt(checkpoint_dir, t_next, tree, extra)
+
+    def _checkpoint_state():
         seen_keys = [k for c in range(n_cells) for k in seen[c]]
         seen_cell = [c for c in range(n_cells) for _ in seen[c]]
         xdim = SAC_STATE_DIM + act.N_CONT
@@ -581,158 +606,189 @@ def run_search_cells(workload: Workload, node_nms: Sequence[int], *,
                           screen_k=sc.screen_k,
                           gate_threshold=sc.gate_threshold),
             screen_rng=screen_rng.bit_generator.state)
-        _save_search_ckpt(checkpoint_dir, t_next, tree, extra)
+        return tree, extra
 
     for t in range(start_t, n_steps):
-        _dt0 = time.time()
-        key, k_act, k_upd, k_mpc = jax.random.split(key, 4)
-        # ---- action selection: per-element eps-greedy (Alg. 1 l.6) -------
-        a_c_rand, a_d_rand = act.random_action_batch(rng, b)
-        a_c_pol, a_d_pol = _policy_act(
-            sac_state.params.actor, jnp.asarray(s), k_act)
-        a_c_pol, a_d_pol = np.asarray(a_c_pol), np.asarray(a_d_pol)
-        if (eps_sched.eps < sc.mpc_eps_gate and surrogate.accepted
-                and wm_mod.trained(wm_state)):
-            a_mpc = np.asarray(_plan_batch(
-                sac_state.params.actor, wm_state.params, surrogate.params,
-                jnp.asarray(s), jax.random.split(k_mpc, b)))
-            blend = (mpc_mod.BLEND_MPC * a_mpc
-                     + (1.0 - mpc_mod.BLEND_MPC) * a_c_pol)
-            a_c_pol[:, :mpc_mod.TCC_ACTION_DIMS] = \
-                blend[:, :mpc_mod.TCC_ACTION_DIMS]
-        explore = rng.random(b) < eps_sched.eps
-        a_c = np.where(explore[:, None], a_c_rand, a_c_pol).astype(np.float32)
-        a_d = np.where(explore[:, None], a_d_rand, a_d_pol).astype(np.int32)
-        # ---- surrogate-gated screening (Eq. 67): K candidates per env,
-        # surrogate scores them in one fused call, the top-1 survivor gets
-        # the analytic evaluation.  Candidate 0 is the exact ungated action;
-        # extra candidates draw from the dedicated screen streams, so cells
-        # whose gate is closed keep the ungated action stream untouched.
-        if gate_on and gate.open.any():
-            kk = sc.screen_k
-            cand_c = np.empty((b, kk, act.N_CONT), np.float32)
-            cand_d = np.empty((b, kk, act.N_DISC), np.int32)
-            cand_c[:, 0], cand_d[:, 0] = a_c, a_d
-            screen_key, k_scr = jax.random.split(screen_key)
-            p_c, p_d = _policy_act(
-                sac_state.params.actor,
-                jnp.asarray(np.repeat(s, kk - 1, axis=0)), k_scr)
-            r_c, r_d = act.random_action_batch(screen_rng, b * (kk - 1))
-            expl = screen_rng.random(b * (kk - 1)) < eps_sched.eps
-            cand_c[:, 1:] = np.where(expl[:, None], r_c,
-                                     np.asarray(p_c)).reshape(b, kk - 1, -1)
-            cand_d[:, 1:] = np.where(expl[:, None], r_d,
-                                     np.asarray(p_d)).reshape(b, kk - 1, -1)
-            pick = np.asarray(_screen(
-                surrogate.params, jnp.asarray(s), jnp.asarray(cand_c),
-                env.weights, jnp.asarray(np.repeat(gate.open, lanes))))
-            a_c = cand_c[np.arange(b), pick]
-            a_d = cand_d[np.arange(b), pick]
-        # ---- env transition: one fused dispatch for B env-steps ----------
-        s2, r, info = env.step(a_c, a_d)
-        buf.add_batch(s, a_c, a_d, r, s2, np.zeros(b, np.float32))
-        sur_x.append(np.concatenate([s, a_c], axis=1).astype(np.float32))
-        sur_y.append(info.metrics.astype(np.float32))
-        # ---- per-cell best tracking + batched Pareto insert (l.15) -------
-        improved = False
-        scores = info.metrics[:, M_IDX["ppa_score"]]
-        for c in range(n_cells):
-            lo, hi = c * lanes, (c + 1) * lanes
-            feas_idx = lo + np.nonzero(info.feasible[lo:hi])[0]
-            archives[c].insert_batch([
-                ArchiveEntry.from_metrics(info.cfg[i], info.metrics[i],
-                                          episode=t_env + int(i) - lo)
-                for i in feas_idx])
-            if feas_idx.size:
-                j = int(feas_idx[np.argmin(scores[feas_idx])])
-                if float(scores[j]) < best[c][0]:
-                    best[c] = (float(scores[j]), info.cfg[j].copy(),
-                               info.metrics[j].copy())
-                    improved = True
-            feasible_count[c] += int(info.feasible[lo:hi].sum())
-            for i in range(lo, hi):
-                seen[c].add(_cfg_key(info.cfg[i]))
-        t_env += lanes
-        no_improve = 0 if improved else no_improve + lanes
-        # ---- gate accounting + online per-cell calibration (Eq. 66) ------
-        if gate_on:
-            gate.count(lanes, sc.screen_k)
-            # calibration only matters while some gate can still open
-            # (the gate is monotone): skip the dead work once all are open
-            if surrogate.n_updates > 0 and not gate.open.all():
-                errs = np.asarray(sur_mod.calib_errors(
-                    surrogate.params, jnp.asarray(sur_x[-1]),
-                    jnp.asarray(info.metrics)))
-                gate.observe(errs.reshape(n_cells, lanes).mean(axis=1), t_env)
-        else:
-            gate.count(lanes, 1)
-        # ---- learn (Alg. 1 l.12-13) --------------------------------------
-        if buf.size >= max(sc.batch_size, min(sc.warmup, sc.episodes // 4)):
-            for _ in range(sc.updates_per_dispatch):
-                batch_np, idx = buf.sample(sc.batch_size)
-                batch = sac_mod.Batch(**{k: jnp.asarray(v)
-                                         for k, v in batch_np.items()})
-                key, k_upd = jax.random.split(key)
-                sac_state, td_abs, met = sac_mod.update(sac_state, batch,
-                                                        k_upd)
-                buf.update_priorities(idx, np.asarray(td_abs))
-                last_entropy = float(met["entropy"])
-            wmb = buf.recent(sc.wm_batch)
-            wm_state, _ = wm_mod.train_step(
-                wm_state, jnp.asarray(wmb["s"]), jnp.asarray(wmb["a_cont"]),
-                jnp.asarray(wmb["s2"]))
-            if t % max(1, sc.surrogate_every // lanes) == 0 and len(sur_x):
-                xs = np.concatenate(list(sur_x), axis=0)
-                ys = np.concatenate(list(sur_y), axis=0)
-                pick = rng.integers(0, len(xs), size=min(256, len(xs)))
-                surrogate.update(xs[pick], ys[pick])
-        # ---- telemetry feed: clocks + loop counters only -----------------
-        _td = time.time() - _dt0
-        _m_disp.observe(_td)
-        _m_steps.inc(b)
-        _m_sps.set(b / _td if _td > 0 else 0.0)
-        _m_gate.set(float(np.mean(gate.open)))
-        _m_eps.set(eps_sched.eps)
-        _m_ent.set(last_entropy)
-        _m_prio.set(float(buf.max_priority))
-        _m_size.set(float(buf.size))
-        _m_beta.set(float(buf.beta))
-        _bb = min(best[c][0] for c in range(n_cells))
-        if np.isfinite(_bb):
-            _m_best.set(float(_bb))
-        _scr, _ev = float(gate.screened.sum()), float(gate.evaluated.sum())
-        _m_screened.inc(_scr - _prev_scr)
-        _m_evaluated.inc(_ev - _prev_ev)
-        _prev_scr, _prev_ev = _scr, _ev
-        if t == start_t:
-            # the first dispatch pays jit compilation — worth a span of
-            # its own on the timeline
-            obs_trace.complete("first_dispatch", _dt0, _td, cat="search",
-                               cells=n_cells, lanes=lanes)
-        # ---- epsilon decay: one per per-cell env-step (Eq. 9) ------------
-        found = bool(feasible_count.sum() > 0)
-        for _ in range(lanes):
-            eps_sched.step(found_feasible=found)
-        if t % trace_every == 0 or t == n_steps - 1:
-            for c in range(n_cells):
-                lo, hi = c * lanes, (c + 1) * lanes
-                traces[c].append(TracePoint(
-                    episode=t_env, reward=float(np.mean(r[lo:hi])),
-                    best_score=float(best[c][0]), eps=eps_sched.eps,
-                    entropy=last_entropy, unique_configs=len(seen[c]),
-                    feasible_count=int(feasible_count[c]),
-                    tok_s=float(np.mean(
-                        info.metrics[lo:hi, M_IDX["tok_s"]]))))
-            obs_trace.counter("search", env_steps_s=(b / _td if _td > 0
-                                                     else 0.0),
-                              eps=eps_sched.eps,
-                              gate_open_frac=float(np.mean(gate.open)),
-                              feasible=float(feasible_count.sum()))
-            if sc.verbose:
-                bb = min(float(best[c][0]) for c in range(n_cells))
-                print(f"  step {t:5d} (ep {t_env}) r={float(np.mean(r)):+.3f} "
-                      f"best={bb:.4f} eps={eps_sched.eps:.3f} "
-                      f"feas={int(feasible_count.sum())}")
+        # the first dispatch pays jit compilation: its annotation carries
+        # the name of its JSONL span
+        with obs_trace.step("first_dispatch" if t == start_t
+                            else "dispatch", t):
+            _dt0, _pc0 = time.time(), time.perf_counter()
+            with obs_trace.phase("act", _m_phase["act"]):
+                key, k_act, k_upd, k_mpc = jax.random.split(key, 4)
+                # ---- action selection: per-element eps-greedy (l.6) ----
+                a_c_rand, a_d_rand = act.random_action_batch(rng, b)
+                a_c_pol, a_d_pol = _policy_act(
+                    sac_state.params.actor, jnp.asarray(s), k_act)
+                a_c_pol, a_d_pol = np.asarray(a_c_pol), np.asarray(a_d_pol)
+                if (eps_sched.eps < sc.mpc_eps_gate and surrogate.accepted
+                        and wm_mod.trained(wm_state)):
+                    a_mpc = np.asarray(_plan_batch(
+                        sac_state.params.actor, wm_state.params,
+                        surrogate.params, jnp.asarray(s),
+                        jax.random.split(k_mpc, b)))
+                    blend = (mpc_mod.BLEND_MPC * a_mpc
+                             + (1.0 - mpc_mod.BLEND_MPC) * a_c_pol)
+                    a_c_pol[:, :mpc_mod.TCC_ACTION_DIMS] = \
+                        blend[:, :mpc_mod.TCC_ACTION_DIMS]
+                explore = rng.random(b) < eps_sched.eps
+                a_c = np.where(explore[:, None], a_c_rand,
+                               a_c_pol).astype(np.float32)
+                a_d = np.where(explore[:, None], a_d_rand,
+                               a_d_pol).astype(np.int32)
+                # ---- surrogate-gated screening (Eq. 67): K candidates
+                # per env, surrogate scores them in one fused call, the
+                # top-1 survivor gets the analytic evaluation.  Candidate
+                # 0 is the exact ungated action; extra candidates draw
+                # from the dedicated screen streams, so cells whose gate
+                # is closed keep the ungated action stream untouched.
+                if gate_on and gate.open.any():
+                    kk = sc.screen_k
+                    cand_c = np.empty((b, kk, act.N_CONT), np.float32)
+                    cand_d = np.empty((b, kk, act.N_DISC), np.int32)
+                    cand_c[:, 0], cand_d[:, 0] = a_c, a_d
+                    screen_key, k_scr = jax.random.split(screen_key)
+                    p_c, p_d = _policy_act(
+                        sac_state.params.actor,
+                        jnp.asarray(np.repeat(s, kk - 1, axis=0)), k_scr)
+                    r_c, r_d = act.random_action_batch(screen_rng,
+                                                       b * (kk - 1))
+                    expl = screen_rng.random(b * (kk - 1)) < eps_sched.eps
+                    cand_c[:, 1:] = np.where(
+                        expl[:, None], r_c,
+                        np.asarray(p_c)).reshape(b, kk - 1, -1)
+                    cand_d[:, 1:] = np.where(
+                        expl[:, None], r_d,
+                        np.asarray(p_d)).reshape(b, kk - 1, -1)
+                    pick = np.asarray(_screen(
+                        surrogate.params, jnp.asarray(s),
+                        jnp.asarray(cand_c), env.weights,
+                        jnp.asarray(np.repeat(gate.open, lanes))))
+                    a_c = cand_c[np.arange(b), pick]
+                    a_d = cand_d[np.arange(b), pick]
+            # ---- env transition: one fused dispatch for B env-steps ----
+            with obs_trace.phase("env_step", _m_phase["env_step"]):
+                s2, r, info = env.step(a_c, a_d)
+            with obs_trace.phase("archive", _m_phase["archive"]):
+                buf.add_batch(s, a_c, a_d, r, s2, np.zeros(b, np.float32))
+                sur_x.append(np.concatenate([s, a_c],
+                                            axis=1).astype(np.float32))
+                sur_y.append(info.metrics.astype(np.float32))
+                # ---- per-cell best tracking + batched Pareto insert ----
+                improved = False
+                scores = info.metrics[:, M_IDX["ppa_score"]]
+                for c in range(n_cells):
+                    lo, hi = c * lanes, (c + 1) * lanes
+                    feas_idx = lo + np.nonzero(info.feasible[lo:hi])[0]
+                    archives[c].insert_batch([
+                        ArchiveEntry.from_metrics(
+                            info.cfg[i], info.metrics[i],
+                            episode=t_env + int(i) - lo)
+                        for i in feas_idx])
+                    if feas_idx.size:
+                        j = int(feas_idx[np.argmin(scores[feas_idx])])
+                        if float(scores[j]) < best[c][0]:
+                            best[c] = (float(scores[j]), info.cfg[j].copy(),
+                                       info.metrics[j].copy())
+                            improved = True
+                    feasible_count[c] += int(info.feasible[lo:hi].sum())
+                    for i in range(lo, hi):
+                        seen[c].add(_cfg_key(info.cfg[i]))
+                t_env += lanes
+                no_improve = 0 if improved else no_improve + lanes
+                # ---- gate accounting + online calibration (Eq. 66) ----
+                if gate_on:
+                    gate.count(lanes, sc.screen_k)
+                    # calibration only matters while some gate can still
+                    # open (the gate is monotone): skip the dead work once
+                    # all are open
+                    if surrogate.n_updates > 0 and not gate.open.all():
+                        errs = np.asarray(sur_mod.calib_errors(
+                            surrogate.params, jnp.asarray(sur_x[-1]),
+                            jnp.asarray(info.metrics)))
+                        gate.observe(errs.reshape(n_cells,
+                                                  lanes).mean(axis=1),
+                                     t_env)
+                else:
+                    gate.count(lanes, 1)
+            # ---- learn (Alg. 1 l.12-13) ----------------------------------
+            with obs_trace.phase("learn", _m_phase["learn"]):
+                if buf.size >= max(sc.batch_size,
+                                   min(sc.warmup, sc.episodes // 4)):
+                    for _ in range(sc.updates_per_dispatch):
+                        with obs_trace.phase("learn.sample"):
+                            batch_np, idx = buf.sample(sc.batch_size)
+                            batch = sac_mod.Batch(**{
+                                k: jnp.asarray(v)
+                                for k, v in batch_np.items()})
+                        with obs_trace.phase("learn.update"):
+                            key, k_upd = jax.random.split(key)
+                            sac_state, td_abs, met = sac_mod.update(
+                                sac_state, batch, k_upd)
+                        with obs_trace.phase("learn.priorities"):
+                            buf.update_priorities(idx, np.asarray(td_abs))
+                            last_entropy = float(met["entropy"])
+                    with obs_trace.phase("learn.wm"):
+                        wmb = buf.recent(sc.wm_batch)
+                        wm_state, _ = wm_mod.train_step(
+                            wm_state, jnp.asarray(wmb["s"]),
+                            jnp.asarray(wmb["a_cont"]),
+                            jnp.asarray(wmb["s2"]))
+                    if (t % max(1, sc.surrogate_every // lanes) == 0
+                            and len(sur_x)):
+                        with obs_trace.phase("learn.surrogate"):
+                            xs = np.concatenate(list(sur_x), axis=0)
+                            ys = np.concatenate(list(sur_y), axis=0)
+                            pick = rng.integers(0, len(xs),
+                                                size=min(256, len(xs)))
+                            surrogate.update(xs[pick], ys[pick])
+            _td = time.perf_counter() - _pc0
+            # ---- telemetry feed: clocks + loop counters only -------------
+            with obs_trace.phase("telemetry", _m_phase["telemetry"]):
+                _m_disp.observe(_td)
+                _m_steps.inc(b)
+                _m_sps.set(b / _td if _td > 0 else 0.0)
+                _m_gate.set(float(np.mean(gate.open)))
+                _m_eps.set(eps_sched.eps)
+                _bb = min(best[c][0] for c in range(n_cells))
+                if np.isfinite(_bb):
+                    _m_best.set(float(_bb))
+                _scr = float(gate.screened.sum())
+                _ev = float(gate.evaluated.sum())
+                _m_screened.inc(_scr - _prev_scr)
+                _m_evaluated.inc(_ev - _prev_ev)
+                _prev_scr, _prev_ev = _scr, _ev
+                if t == start_t:
+                    obs_trace.complete("first_dispatch", _dt0, _td,
+                                       cat="search", cells=n_cells,
+                                       lanes=lanes)
+                # ---- epsilon decay: one per per-cell env-step (Eq. 9) ----
+                found = bool(feasible_count.sum() > 0)
+                for _ in range(lanes):
+                    eps_sched.step(found_feasible=found)
+                if t % trace_every == 0 or t == n_steps - 1:
+                    for c in range(n_cells):
+                        lo, hi = c * lanes, (c + 1) * lanes
+                        traces[c].append(TracePoint(
+                            episode=t_env, reward=float(np.mean(r[lo:hi])),
+                            best_score=float(best[c][0]), eps=eps_sched.eps,
+                            entropy=last_entropy,
+                            unique_configs=len(seen[c]),
+                            feasible_count=int(feasible_count[c]),
+                            tok_s=float(np.mean(
+                                info.metrics[lo:hi, M_IDX["tok_s"]]))))
+                    obs_trace.counter(
+                        "search",
+                        env_steps_s=(b / _td if _td > 0 else 0.0),
+                        eps=eps_sched.eps,
+                        gate_open_frac=float(np.mean(gate.open)),
+                        feasible=float(feasible_count.sum()))
+                    if sc.verbose:
+                        bb = min(float(best[c][0]) for c in range(n_cells))
+                        print(f"  step {t:5d} (ep {t_env}) "
+                              f"r={float(np.mean(r)):+.3f} best={bb:.4f} "
+                              f"eps={eps_sched.eps:.3f} "
+                              f"feas={int(feasible_count.sum())}")
         if t % reset_every == reset_every - 1:
             s = env.reset()
         else:

@@ -49,6 +49,8 @@ from repro.campaign.store import CampaignStore
 from repro.configs import ARCH_IDS, get_config
 from repro.core.pareto import ArchiveEntry, ParetoArchive
 from repro.launch.compile_cache import enable_compile_cache
+from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 from repro.ppa import config_space as cs
 from repro.ppa import surrogate as sur_mod
 from repro.ppa.analytic import NODE_DIM, node_vector
@@ -377,16 +379,19 @@ class Recommender:
 
     def recommend_batch(self, queries: Sequence[Query]) -> List[Answer]:
         """Answer a batch: exact lookups host-side, every surrogate
-        fallback fused into one ``score_query_batch`` dispatch."""
+        fallback fused into one ``score_query_batch`` dispatch, timed from
+        the call until ``device_get`` returns into
+        ``serve_score_dispatch_seconds``."""
         import jax
         answers: List[Optional[Answer]] = [None] * len(queries)
         pend: List[int] = []
-        for i, q in enumerate(queries):
-            ans = self._exact(q)
-            if ans is not None:
-                answers[i] = ans
-            else:
-                pend.append(i)
+        with obs_trace.phase("exact"):
+            for i, q in enumerate(queries):
+                ans = self._exact(q)
+                if ans is not None:
+                    answers[i] = ans
+                else:
+                    pend.append(i)
         self.n_exact += len(queries) - len(pend)
         self.n_surrogate += len(pend)
         if pend:
@@ -406,14 +411,19 @@ class Recommender:
             wts = np.asarray([q.weights for q in qs], np.float32)
             wts /= np.maximum(wts.sum(axis=1, keepdims=True),
                               np.float32(1e-9))
+            budgets = np.asarray([q.power_budget_mw for q in qs],
+                                 np.float32)
+            perfs = np.asarray([q.min_perf_gops for q in qs], np.float32)
             # numpy args go straight to the jit boundary (jit device_puts
             # them once — pre-wrapping in jnp.asarray pays the copy twice)
-            out = sur_mod.score_query_batch(
-                self.surrogate.params, q_arr, self._cand, wts,
-                np.asarray([q.power_budget_mw for q in qs], np.float32),
-                np.asarray([q.min_perf_gops for q in qs], np.float32))
+            m_score = obs_metrics.global_registry().histogram(
+                "serve_score_dispatch_seconds")
+            with obs_trace.phase("score_dispatch", m_score):
+                out = sur_mod.score_query_batch(
+                    self.surrogate.params, q_arr, self._cand, wts,
+                    budgets, perfs)
+                idx, pred, within = jax.device_get(out)
             self.n_dispatches += 1
-            idx, pred, within = jax.device_get(out)
             idx = idx.tolist()
             preds = pred.astype(np.float64).tolist()
             within = within.tolist()

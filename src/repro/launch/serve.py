@@ -96,16 +96,21 @@ def recommend_server(roots, *, host: str = "127.0.0.1", port: int = 8177,
     (``port=0`` picks an ephemeral port, readable as
     ``srv.server_port``).
     """
+    import itertools
     import json
     from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
     from repro.launch.recommend import Query, Recommender
     from repro.obs import metrics as obs_metrics
+    from repro.obs import trace as obs_trace
 
     rec = recommender or Recommender.build(list(roots))
     # jit dispatches mutate shared trace caches; serialize query batches
     import threading
     lock = threading.Lock()
+    # request ids: every span of a request carries its id on a profile
+    request_ids = itertools.count()
+    obs_trace.watch_gc()
     t_started = time.time()
     reg = obs_metrics.global_registry()
     m_requests = {p: reg.counter("serve_requests_total",
@@ -118,6 +123,9 @@ def recommend_server(roots, *, host: str = "127.0.0.1", port: int = 8177,
                               labels={"source": "surrogate"})
     m_dispatch = reg.counter("serve_fused_dispatches_total")
     m_latency = reg.histogram("serve_request_seconds")
+    # asking for the lock until it is held, and recommend_batch under it
+    m_lock_wait = reg.histogram("serve_lock_wait_seconds")
+    m_lock_hold = reg.histogram("serve_lock_hold_seconds")
 
     class Handler(BaseHTTPRequestHandler):
         def log_message(self, fmt, *args):  # quiet: stderr stays for errors
@@ -169,40 +177,57 @@ def recommend_server(roots, *, host: str = "127.0.0.1", port: int = 8177,
             finally:
                 m_latency.observe(time.time() - t0)
 
+        def _parse(self) -> list:
+            n = int(self.headers.get("Content-Length", 0))
+            req = json.loads(self.rfile.read(n) or b"{}")
+            if not isinstance(req, dict):
+                raise ValueError(
+                    "request body must be a JSON object, got "
+                    f"{type(req).__name__}")
+            qd = req.get("queries", [])
+            if not isinstance(qd, list):
+                raise ValueError(
+                    "'queries' must be a list of objects, got "
+                    f"{type(qd).__name__}")
+            queries = []
+            for i, d in enumerate(qd):
+                if not isinstance(d, dict):
+                    raise ValueError(
+                        f"queries[{i}] must be a JSON object, "
+                        f"got {type(d).__name__}")
+                queries.append(Query.from_dict(d))
+            if not queries:
+                raise ValueError("request carries no queries")
+            return queries
+
         def do_POST(self):
             t0 = time.time()
             self._count()
             try:
-                if self.path != "/recommend":
-                    self._reply(404, {"error": f"no route {self.path}"})
-                    return
+                with obs_trace.tagged(req=next(request_ids)), \
+                        obs_trace.phase("request"):
+                    self._recommend()
+            finally:
+                m_latency.observe(time.time() - t0)
+
+        def _recommend(self) -> None:
+            if self.path != "/recommend":
+                self._reply(404, {"error": f"no route {self.path}"})
+                return
+            try:
+                with obs_trace.phase("parse"):
+                    queries = self._parse()
+                with obs_trace.phase("lock_wait", m_lock_wait):
+                    lock.acquire()
                 try:
-                    n = int(self.headers.get("Content-Length", 0))
-                    req = json.loads(self.rfile.read(n) or b"{}")
-                    if not isinstance(req, dict):
-                        raise ValueError(
-                            "request body must be a JSON object, got "
-                            f"{type(req).__name__}")
-                    qd = req.get("queries", [])
-                    if not isinstance(qd, list):
-                        raise ValueError(
-                            "'queries' must be a list of objects, got "
-                            f"{type(qd).__name__}")
-                    queries = []
-                    for i, d in enumerate(qd):
-                        if not isinstance(d, dict):
-                            raise ValueError(
-                                f"queries[{i}] must be a JSON object, "
-                                f"got {type(d).__name__}")
-                        queries.append(Query.from_dict(d))
-                    if not queries:
-                        raise ValueError("request carries no queries")
-                    with lock:
+                    with obs_trace.phase("lock_hold", m_lock_hold):
                         before = rec.n_dispatches
                         answers = rec.recommend_batch(queries)
                         used = rec.n_dispatches - before
-                    n_ex = sum(1 for a in answers
-                               if a.source == "archive")
+                finally:
+                    lock.release()
+                with obs_trace.phase("reply"):
+                    n_ex = sum(1 for a in answers if a.source == "archive")
                     m_exact.inc(n_ex)
                     m_surrogate.inc(len(answers) - n_ex)
                     m_dispatch.inc(used)
@@ -210,15 +235,13 @@ def recommend_server(roots, *, host: str = "127.0.0.1", port: int = 8177,
                         "answers": [a.to_dict() for a in answers],
                         "dispatches": used,
                     })
-                except (ValueError, TypeError, KeyError,
-                        json.JSONDecodeError) as e:
-                    # malformed input is the CLIENT's 400, with a payload
-                    # that says what was wrong — never a bare 500
-                    m_bad.inc()
-                    self._reply(400, {"error": {
-                        "type": type(e).__name__, "message": str(e)}})
-            finally:
-                m_latency.observe(time.time() - t0)
+            except (ValueError, TypeError, KeyError,
+                    json.JSONDecodeError) as e:
+                # malformed input is the CLIENT's 400, with a payload
+                # that says what was wrong — never a bare 500
+                m_bad.inc()
+                self._reply(400, {"error": {
+                    "type": type(e).__name__, "message": str(e)}})
 
     srv = ThreadingHTTPServer((host, port), Handler)
     print(f"[serve] recommendation server on http://{host}:{srv.server_port}"

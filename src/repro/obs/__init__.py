@@ -6,7 +6,9 @@ server):
 
 * :mod:`repro.obs.trace`   — ``Span``/``trace()`` crash-safe JSONL span
   logs (one ``trace.jsonl`` per process, Chrome/Perfetto-exportable via
-  ``python -m repro.obs.export``);
+  ``python -m repro.obs.export``), ``phase()`` hot-path timers that feed
+  registry histograms, and ``watch_gc()`` for GC pauses; each is also a
+  ``repro.<name>`` annotation on a JAX profile being taken;
 * :mod:`repro.obs.metrics` — ``MetricsRegistry`` counters / gauges /
   fixed-bucket histograms with deterministic aggregation and a
   Prometheus text rendering (the serve ``/metrics`` surface and the

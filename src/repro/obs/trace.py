@@ -24,19 +24,38 @@ With no tracer installed (or ``REPRO_TRACE=0``) every hook is a shared
 no-op object — the disabled path costs one global read.  Tracing never
 touches RNG streams or checkpoint contents: a traced search is bitwise
 identical to an untraced one (test-enforced).
+
+Hot paths time their blocks with :func:`phase` instead, which writes no
+JSONL record: it adds the block's duration to a registry histogram::
+
+    with phase("env_step", hist):         # hist: a metrics Histogram
+        ...
+
+While a JAX profile is being taken, every span, phase and GC pause
+(:func:`watch_gc`) also opens a profiler annotation named ``repro.<name>``
+on the profile's host plane, on the device trace's clock and nested like
+the code.  This module never imports jax: it uses the profiler only once
+something else has loaded jax, so JAX-free processes stay JAX-free.
 """
 from __future__ import annotations
 
+import contextlib
+import gc
 import json
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
 
 from repro.core import fsutil
+from repro.obs import metrics as obs_metrics
 
 TRACE_NAME = "trace.jsonl"
 TRACE_ENV = "REPRO_TRACE"
+# every program annotation on a profile is named PREFIX + the span's name,
+# which sets the program's annotations apart from the runtime's
+PREFIX = "repro."
 
 # trace_event phases we emit: complete span / instant / counter
 PH_SPAN, PH_INSTANT, PH_COUNTER = "X", "i", "C"
@@ -48,14 +67,145 @@ def tracing_disabled() -> bool:
         "0", "off", "false", "no")
 
 
+# ------------------------------------------------- profiler annotations
+_annotation_cls = None
+
+
+def _profiler_annotation():
+    """jax's ``TraceAnnotation`` once jax is loaded, else None."""
+    global _annotation_cls
+    if _annotation_cls is None and "jax" in sys.modules:
+        from jax import profiler    # jax is loaded already: no import cost
+        _annotation_cls = profiler.TraceAnnotation
+    return _annotation_cls
+
+
+def profiling() -> bool:
+    """True while a JAX profile is being taken in this process."""
+    cls = _profiler_annotation()
+    return cls is not None and cls.is_enabled()
+
+
+_tags = threading.local()
+
+
+@contextlib.contextmanager
+def tagged(**meta):
+    """Put ``meta`` (say, a request id) on every annotation this thread
+    opens inside the block, besides each one's own."""
+    prev = getattr(_tags, "meta", {})
+    _tags.meta = {**prev, **meta}
+    try:
+        yield
+    finally:
+        _tags.meta = prev
+
+
+def _annotate(name: str, meta: Optional[Dict] = None):
+    """Open and return the profiler annotation ``PREFIX + name`` (``meta``
+    and the thread's tags become its stats), or None when no profile is
+    being taken."""
+    cls = _profiler_annotation()
+    if cls is None or not cls.is_enabled():
+        return None
+    ann = cls(PREFIX + name, **{**getattr(_tags, "meta", {}), **(meta or {})})
+    ann.__enter__()
+    return ann
+
+
+def _close(ann) -> None:
+    if ann is not None:
+        ann.__exit__(None, None, None)
+
+
+class Phase:
+    """A timed block of a hot path: its duration goes to ``hist`` (when
+    given) and, while a profile is being taken, it is the annotation
+    ``PREFIX + name``.  One clock read at each end, no JSONL record."""
+
+    __slots__ = ("name", "hist", "meta", "t0", "_ann")
+
+    def __init__(self, name: str, hist, meta: Dict):
+        self.name = name
+        self.hist = hist
+        self.meta = meta
+        self.t0 = 0.0
+        self._ann = None
+
+    def __enter__(self) -> "Phase":
+        self._ann = _annotate(self.name, self.meta)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, et, ev, tb) -> None:
+        dt = time.perf_counter() - self.t0
+        if self.hist is not None:
+            self.hist.observe(dt)
+        _close(self._ann)
+
+
+def phase(name: str, hist=None, **meta):
+    """Time a block of a hot path into ``hist`` (a metrics ``Histogram``)
+    and annotate it on a profile being taken; ``meta`` (say, a request
+    id) goes on the annotation only.  Without ``hist`` the block is
+    profile-only and costs one check when no profile is being taken."""
+    if hist is None and not profiling():
+        return NULL_SPAN
+    return Phase(name, hist, meta)
+
+
+def step(name: str, step_num: int):
+    """Profile-only step annotation (``StepTraceAnnotation`` style) around
+    one iteration of a loop."""
+    return phase(name, _r=1, step_num=step_num)
+
+
+# ---------------------------------------------------------- GC pauses
+GC_HIST = "gc_pause_seconds"
+_gc = dict(installed=False, t0=0.0, ann=None, hists=())
+
+
+def _on_gc(ph: str, info: Dict) -> None:
+    # nothing here imports, locks or creates an instrument: a collection
+    # can start inside any of those
+    if ph == "start":
+        _gc["ann"] = (_annotate("gc", dict(gen=info["generation"]))
+                      if _annotation_cls is not None else None)
+        _gc["t0"] = time.perf_counter()
+        return
+    dt = time.perf_counter() - _gc["t0"]
+    hists = _gc["hists"]
+    if hists:
+        hists[min(info["generation"], len(hists) - 1)].observe(dt)
+    ann, _gc["ann"] = _gc["ann"], None
+    _close(ann)
+
+
+def watch_gc() -> None:
+    """Record every collection's pause into ``gc_pause_seconds{gen=...}``
+    of the global registry (and as a ``repro.gc`` annotation on a profile
+    being taken).  Installs one ``gc.callbacks`` hook per process, once;
+    every call binds the histograms again, so call it where a hot path
+    starts and a registry cleared before then is fed again.  Collections
+    never overlap, so one start time serves every thread."""
+    reg = obs_metrics.global_registry()
+    _gc["hists"] = tuple(reg.histogram(GC_HIST, labels={"gen": str(g)})
+                         for g in range(3))
+    if not _gc["installed"]:
+        _gc["installed"] = True
+        gc.callbacks.append(_on_gc)
+
+
+# ---------------------------------------------------------------- spans
 class Span:
-    """One in-flight span; emitted as a single JSONL record on exit.
+    """One in-flight span; emitted as a single JSONL record on exit, and
+    the annotation ``PREFIX + name`` on a profile being taken.
 
     ``set(**args)`` attaches result arguments any time before exit; an
     exception propagating through the span is recorded under
     ``args["error"]`` (and re-raised untouched)."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: Dict):
         self._tracer = tracer
@@ -63,12 +213,14 @@ class Span:
         self.cat = cat
         self.args = args
         self.t0 = 0.0
+        self._ann = None
 
     def set(self, **args) -> "Span":
         self.args.update(args)
         return self
 
     def __enter__(self) -> "Span":
+        self._ann = _annotate(self.name)
         self.t0 = time.time()
         return self
 
@@ -76,6 +228,7 @@ class Span:
         if et is not None:
             self.args.setdefault("error", repr(ev))
         t1 = time.time()
+        _close(self._ann)
         self._tracer.emit(dict(
             ph=PH_SPAN, name=self.name, cat=self.cat, ts=self.t0,
             dur=t1 - self.t0, tid=self._tracer._tid(),

@@ -4,6 +4,9 @@ merge, lease-metrics piggyback round-trip, the fleet ``--status`` view,
 Prometheus text rendering + the serve ``/metrics`` endpoint, the
 structured-400 regression, supervision-event formatting, and the
 contract that tracing never perturbs search results (bitwise)."""
+import contextlib
+import gc
+import glob
 import json
 import os
 import threading
@@ -14,7 +17,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core.search import SearchConfig, run_search_cells
+from repro.core.search import PHASES, SearchConfig, run_search_cells
 from repro.obs import export as obs_export
 from repro.obs import log as obs_log
 from repro.obs import metrics as obs_metrics
@@ -359,11 +362,128 @@ def test_tracing_on_off_bitwise_identical_search(tmp_path):
     obs_trace.install_tracer(tr)
     try:
         on = fp(run_search_cells(wl, [3, 7], search=sc, lanes_per_cell=4))
+        # phases timed and annotated on a profile being taken, too
+        with _profile(tmp_path / "profile"):
+            profiled = fp(run_search_cells(wl, [3, 7], search=sc,
+                                           lanes_per_cell=4))
     finally:
         obs_trace.install_tracer(None)
         tr.close()
     assert on == off
+    assert profiled == off
+    assert obs_metrics.global_registry().histogram(
+        "search_phase_seconds", labels={"phase": "act"}).count > 0
     # and the traced run actually produced spans
     names = {r["name"] for r in obs_trace.read_trace(
         str(tmp_path / "trace.jsonl"))}
     assert "run_search_cells" in names and "first_dispatch" in names
+
+
+# ------------------------------------- phases, profile annotations, GC
+@contextlib.contextmanager
+def _profile(log_dir):
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0        # annotations only, as bench/run.py
+    opts.host_tracer_level = 2
+    with jax.profiler.trace(str(log_dir), profiler_options=opts):
+        yield
+
+
+def _host_events(log_dir):
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+             dict(e.stats) if e.name.startswith("repro.") else {})
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def _small_search(**kw):
+    wl = extract(get_config(ARCH), seq_len=256, batch=1)
+    sc = SearchConfig(episodes=64, warmup=24, batch_size=32, seed=0)
+    return run_search_cells(wl, [3, 7], search=sc, lanes_per_cell=4, **kw)
+
+
+def test_search_phases_account_for_the_dispatch():
+    reg = obs_metrics.global_registry()
+    reg.clear()
+    _small_search()
+    snap = reg.snapshot()
+    disp = obs_metrics.snapshot_value(snap, "histograms", "dispatch_seconds")
+    phases = {p: obs_metrics.snapshot_value(
+        snap, "histograms", "search_phase_seconds", {"phase": p})
+        for p in PHASES}
+    assert disp["count"] == 16
+    assert all(h["count"] == disp["count"] and h["sum"] > 0
+               for h in phases.values())
+    inside = sum(phases[p]["sum"] for p in PHASES if p != "telemetry")
+    assert 0.9 * disp["sum"] <= inside <= disp["sum"]
+    # the unread gauges are gone; the read ones stay
+    gauges = {row["name"] for row in snap["gauges"]}
+    assert {"env_steps_per_s", "gate_open_frac", "search_eps",
+            "best_score"} <= gauges
+    assert not gauges & {"sac_entropy", "per_max_priority", "per_size",
+                         "per_beta"}
+
+
+def test_profile_holds_phases_inside_the_dispatch(tmp_path):
+    _small_search()                     # compiled outside the profile
+    with _profile(tmp_path):
+        _small_search(checkpoint_dir=str(tmp_path / "ckpt"),
+                      checkpoint_every=4)
+    events = _host_events(tmp_path)
+    names = {e[0] for e in events}
+    disp = [e for e in events
+            if e[0] in ("repro.dispatch", "repro.first_dispatch")]
+    assert len(disp) == 16
+    assert "repro.run_search_cells" in names
+    for p in PHASES + ("learn.sample", "learn.update", "learn.priorities",
+                       "learn.wm"):
+        inner = [e for e in events if e[0] == "repro." + p]
+        assert inner, p
+        assert all(any(d[1] <= e[1] and e[2] <= d[2] for d in disp)
+                   for e in inner), p
+    for p in ("gather", "serialize", "fsync", "publish"):
+        assert "repro.checkpoint." + p in names
+    # every program annotation carries the program's prefix; the
+    # runtime's own never do
+    assert not {n for n in names if n.startswith("repro")
+                and not n.startswith("repro.")}
+
+
+def test_gc_pause_is_recorded_once_per_collection():
+    obs_trace.watch_gc()
+    obs_trace.watch_gc()
+    assert gc.callbacks.count(obs_trace._on_gc) == 1
+    h = obs_metrics.global_registry().histogram(
+        obs_trace.GC_HIST, labels={"gen": "2"})
+    n, total = h.count, h.sum
+    gc.collect()
+    assert h.count == n + 1 and h.sum > total
+
+
+def test_phase_without_profile_or_histogram_is_the_null_span():
+    assert not obs_trace.profiling()
+    assert obs_trace.phase("x") is obs_trace.NULL_SPAN
+    h = obs_metrics.MetricsRegistry().histogram("t")
+    with obs_trace.phase("x", h, req=3):
+        pass
+    assert h.count == 1 and h.sum >= 0.0
+
+
+def test_tagged_meta_reaches_every_annotation_of_the_thread(tmp_path):
+    import jax  # noqa: F401  (annotations need jax loaded)
+    with _profile(tmp_path):
+        with obs_trace.tagged(req=7), obs_trace.phase("request"):
+            with obs_trace.phase("inner", step_num=1):
+                pass
+        with obs_trace.phase("untagged"):
+            pass
+    stats = {e[0]: e[3] for e in _host_events(tmp_path)
+             if e[0].startswith("repro.")}
+    assert stats["repro.request"] == {"req": 7}
+    assert stats["repro.inner"] == {"req": 7, "step_num": 1}
+    assert stats["repro.untagged"] == {}

@@ -12,6 +12,7 @@ The correctness contract under test:
 """
 import json
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -257,3 +258,56 @@ def test_http_healthz_and_bad_query(campaign_root, rec):
         f"http://127.0.0.1:{box['port']}/healthz", timeout=30))
     t.join(30)
     assert h["status"] == "ok" and h["cells"] == 2 and h["candidates"] > 0
+
+
+@pytest.mark.parametrize("nodes,dispatches",
+                         [((IN_NODE,), 0), ((IN_NODE, OUT_NODE), 1)],
+                         ids=["in_grid", "with_fallback"])
+def test_http_request_times_lock_and_dispatch(campaign_root, rec, nodes,
+                                              dispatches):
+    from repro.obs import metrics as obs_metrics
+    reg = obs_metrics.global_registry()
+    # each is observed before the reply goes out (serve_request_seconds
+    # only after it, on a handler thread the test cannot join)
+    names = ("serve_lock_wait_seconds", "serve_lock_hold_seconds",
+             "serve_score_dispatch_seconds")
+    before = {n: reg.histogram(n).count
+              for n in names + ("serve_request_seconds",)}
+    before_sum = {n: reg.histogram(n).sum
+                  for n in names + ("serve_request_seconds",)}
+    ready = threading.Event()
+    box = {}
+
+    def _go():
+        from repro.launch.serve import recommend_server
+        recommend_server([campaign_root], port=0, recommender=rec,
+                         poll=True, on_ready=lambda s: (
+                             box.update(port=s.server_port), ready.set()))
+
+    t = threading.Thread(target=_go, daemon=True)
+    t.start()
+    assert ready.wait(30)
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{box['port']}/recommend",
+        data=json.dumps({"queries": [{"arch": ARCH, "node_nm": n}
+                                     for n in nodes]}).encode(),
+        headers={"Content-Type": "application/json"})
+    r = json.load(urllib.request.urlopen(req, timeout=30))
+    t.join(30)
+    assert not t.is_alive()
+    assert r["dispatches"] == dispatches
+    added = {n: reg.histogram(n).count - before[n] for n in names}
+    assert added == {"serve_lock_wait_seconds": 1,
+                     "serve_lock_hold_seconds": 1,
+                     "serve_score_dispatch_seconds": dispatches}
+    # the request's own time, observed once its handler returns, holds
+    # its wait and hold
+    served = reg.histogram("serve_request_seconds")
+    deadline = time.time() + 30
+    while served.count == before["serve_request_seconds"] \
+            and time.time() < deadline:
+        time.sleep(0.01)
+    assert served.count == before["serve_request_seconds"] + 1
+    inner = sum(reg.histogram(n).sum for n in names[:2]) \
+        - sum(before_sum[n] for n in names[:2])
+    assert 0 < inner <= served.sum - before_sum["serve_request_seconds"]
