@@ -61,10 +61,37 @@ def _dominates(a: np.ndarray, b: np.ndarray) -> bool:
 
 
 class ParetoArchive:
+    """Non-dominated frontier over (power, -perf, area).
+
+    ``entries`` is the frontier in insertion order.  Next to it the archive
+    caches their objectives as one float64 ``(F, 3)`` matrix, so an insert
+    is a few whole-matrix comparisons instead of a scan per entry.  Assign
+    ``entries`` to replace the frontier (the matrix is rebuilt on first
+    use); the list itself is never mutated in place."""
+
     def __init__(self, max_size: int = 2048):
         self.entries: List[ArchiveEntry] = []
         self.max_size = max_size
         self.n_inserted = 0
+        # candidates that reached the per-entry insert (telemetry only:
+        # not serialized, restarts at 0 on load)
+        self.n_offered = 0
+
+    @property
+    def entries(self) -> List[ArchiveEntry]:
+        return self._entries
+
+    @entries.setter
+    def entries(self, entries: List[ArchiveEntry]) -> None:
+        self._entries = entries
+        self._objs: Optional[np.ndarray] = None
+
+    def _objectives(self) -> np.ndarray:
+        """The frontier's objectives, one float64 row per entry, in order."""
+        if self._objs is None:
+            self._objs = np.array([e.objectives() for e in self._entries],
+                                  np.float64).reshape(-1, 3)
+        return self._objs
 
     def insert(self, entry: ArchiveEntry) -> bool:
         """Insert if non-dominated; evict newly-dominated entries.
@@ -75,25 +102,34 @@ class ParetoArchive:
         ``merge``/``insert_batch`` of overlapping archives would
         accumulate copies on the frontier — bloating archives and zeroing
         the crowd-prune pairwise distances."""
+        return self._insert(entry, np.asarray(entry.objectives(), np.float64))
+
+    def _insert(self, entry: ArchiveEntry, obj: np.ndarray) -> bool:
         self.n_inserted += 1
-        obj = entry.objectives()
-        keep = []
-        for e in self.entries:
-            eo = e.objectives()
-            if _dominates(eo, obj) or np.array_equal(eo, obj):
-                return False          # dominated by (or duplicate of) an
-                                      # existing entry
-            if not _dominates(obj, eo):
-                keep.append(e)
-        keep.append(entry)
-        if len(keep) > self.max_size:  # crowd-prune: drop densest
-            objs = np.stack([e.objectives() for e in keep])
+        self.n_offered += 1
+        objs = self._objectives()
+        # a row <= obj everywhere either dominates obj or equals it; a NaN
+        # compares False, so it neither rejects nor evicts
+        if (objs <= obj).all(axis=1).any():
+            return False
+        # no row equals obj, so obj <= row everywhere means obj dominates it
+        keep = ~(obj <= objs).all(axis=1)
+        if keep.all():
+            entries = self._entries + [entry]
+        else:
+            entries = [e for e, k in zip(self._entries, keep) if k]
+            entries.append(entry)
+            objs = objs[keep]
+        objs = np.concatenate([objs, obj[None]])
+        if len(entries) > self.max_size:  # crowd-prune: drop densest
             span = objs.max(0) - objs.min(0) + 1e-9
             normed = (objs - objs.min(0)) / span
             d = np.linalg.norm(normed[:, None] - normed[None, :], axis=-1)
             np.fill_diagonal(d, np.inf)
-            keep.pop(int(np.argmin(d.min(1))))
-        self.entries = keep
+            i = int(np.argmin(d.min(1)))
+            entries.pop(i)
+            objs = np.delete(objs, i, axis=0)
+        self._entries, self._objs = entries, objs
         return True
 
     def insert_batch(self, entries: Sequence[ArchiveEntry]) -> int:
@@ -101,21 +137,21 @@ class ParetoArchive:
 
         Pre-filters the batch to its own non-dominated subset with one
         vectorized pairwise pass (O(B^2) numpy instead of O(B) frontier
-        scans for entries a batch-mate already dominates), then runs the
+        updates for entries a batch-mate already dominates), then runs the
         usual per-entry frontier update.  The resulting archive equals
         sequential insertion (up to crowd-pruning order at max_size).
         """
         if not entries:
             return 0
-        objs = np.stack([e.objectives() for e in entries])
+        objs = np.array([e.objectives() for e in entries], np.float64)
         le = np.all(objs[:, None, :] <= objs[None, :, :], axis=-1)
         lt = np.any(objs[:, None, :] < objs[None, :, :], axis=-1)
         dominated = (le & lt).any(axis=0)
         self.n_inserted += int(dominated.sum())
         inserted = 0
-        for e, dom in zip(entries, dominated):
+        for e, o, dom in zip(entries, objs, dominated):
             if not dom:
-                inserted += int(self.insert(e))
+                inserted += int(self._insert(e, o))
         return inserted
 
     def select(self, w_perf: float = 0.4, w_power: float = 0.4,

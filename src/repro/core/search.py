@@ -539,6 +539,9 @@ def _search_cells(workload, node_nms, *, high_perf, search, lanes_per_cell,
     _m_gate = _reg.gauge("gate_open_frac")
     _m_eps = _reg.gauge("search_eps")
     _m_best = _reg.gauge("best_score")
+    _m_offered = _reg.counter("pareto_offered_total")
+    _m_kept = _reg.counter("pareto_kept_total")
+    _m_front = _reg.gauge("pareto_frontier_max")
     _m_disp = _reg.histogram("dispatch_seconds")
     # act, env_step, archive and learn cover a dispatch_seconds interval;
     # telemetry follows it, inside the dispatch's annotation
@@ -550,6 +553,7 @@ def _search_cells(workload, node_nms, *, high_perf, search, lanes_per_cell,
     # counters track the delta per dispatch so fleet aggregation sums
     _prev_scr = float(gate.screened.sum())
     _prev_ev = float(gate.evaluated.sum())
+    _prev_off = sum(a.n_offered for a in archives)
 
     def _checkpoint(t_next: int) -> None:
         with obs_trace.phase("checkpoint.gather"):
@@ -676,11 +680,12 @@ def _search_cells(workload, node_nms, *, high_perf, search, lanes_per_cell,
                 sur_y.append(info.metrics.astype(np.float32))
                 # ---- per-cell best tracking + batched Pareto insert ----
                 improved = False
+                kept = 0
                 scores = info.metrics[:, M_IDX["ppa_score"]]
                 for c in range(n_cells):
                     lo, hi = c * lanes, (c + 1) * lanes
                     feas_idx = lo + np.nonzero(info.feasible[lo:hi])[0]
-                    archives[c].insert_batch([
+                    kept += archives[c].insert_batch([
                         ArchiveEntry.from_metrics(
                             info.cfg[i], info.metrics[i],
                             episode=t_env + int(i) - lo)
@@ -758,6 +763,11 @@ def _search_cells(workload, node_nms, *, high_perf, search, lanes_per_cell,
                 _m_screened.inc(_scr - _prev_scr)
                 _m_evaluated.inc(_ev - _prev_ev)
                 _prev_scr, _prev_ev = _scr, _ev
+                _off = sum(a.n_offered for a in archives)
+                _m_offered.inc(_off - _prev_off)
+                _prev_off = _off
+                _m_kept.inc(kept)
+                _m_front.set(max(len(a) for a in archives))
                 if t == start_t:
                     obs_trace.complete("first_dispatch", _dt0, _td,
                                        cat="search", cells=n_cells,

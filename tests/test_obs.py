@@ -429,6 +429,21 @@ def test_search_phases_account_for_the_dispatch():
                          "per_beta"}
 
 
+def test_search_counts_pareto_offers_and_keeps():
+    reg = obs_metrics.global_registry()
+    reg.clear()
+    results = _small_search()
+    snap = reg.snapshot()
+    offered = obs_metrics.snapshot_value(snap, "counters",
+                                         "pareto_offered_total")
+    kept = obs_metrics.snapshot_value(snap, "counters", "pareto_kept_total")
+    assert 0 < kept <= offered
+    assert offered == sum(r.archive.n_offered for r in results)
+    assert obs_metrics.snapshot_value(
+        snap, "gauges", "pareto_frontier_max") == max(
+            len(r.archive) for r in results)
+
+
 def test_profile_holds_phases_inside_the_dispatch(tmp_path):
     _small_search()                     # compiled outside the profile
     with _profile(tmp_path):
