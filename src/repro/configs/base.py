@@ -20,7 +20,7 @@ Family = str  # 'dense' | 'moe' | 'hybrid' | 'vlm' | 'audio' | 'ssm'
 class MLAConfig:
     """Multi-head Latent Attention (MiniCPM3 / DeepSeek-style)."""
     kv_lora_rank: int = 256
-    q_lora_rank: int = 768
+    q_lora_rank: Optional[int] = 768   # None: one d -> H*qk_d query matmul
     qk_nope_head_dim: int = 64
     qk_rope_head_dim: int = 32
     v_head_dim: int = 64
@@ -28,11 +28,12 @@ class MLAConfig:
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int = 8
-    top_k: int = 2
-    d_ff_expert: int = 0          # 0 -> use arch d_ff
+    n_experts: int = 8            # routed experts
+    top_k: int = 2                # routed experts per token
+    d_ff_expert: int = 0          # 0 -> use arch d_ff (routed and shared)
     every: int = 1                # MoE FFN on every `every`-th layer (1=all)
-    shared_expert: bool = False   # Llama-4 style always-on shared expert
+    n_shared: int = 0             # always-on shared experts of d_ff_expert
+    first_k_dense: int = 0        # leading layers with a dense d_ff FFN
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,8 +113,8 @@ class ArchConfig:
         return tuple(kinds)
 
     def moe_on_layer(self, i: int) -> bool:
-        return self.moe is not None and (i % max(1, self.moe.every)
-                                         == max(1, self.moe.every) - 1)
+        return (self.moe is not None and i >= self.moe.first_k_dense
+                and i % max(1, self.moe.every) == max(1, self.moe.every) - 1)
 
     # ---------------- parameter counting (used by ppa + roofline) ----------
     def param_counts(self) -> Dict[str, float]:
@@ -126,7 +127,10 @@ class ArchConfig:
             if self.mla is not None:
                 m = self.mla
                 qk_d = m.qk_nope_head_dim + m.qk_rope_head_dim
-                p = d * m.q_lora_rank + m.q_lora_rank * H * qk_d       # q down/up
+                if m.q_lora_rank is None:
+                    p = d * H * qk_d                                    # q
+                else:
+                    p = d * m.q_lora_rank + m.q_lora_rank * H * qk_d   # q down/up
                 p += d * (m.kv_lora_rank + m.qk_rope_head_dim)          # kv down
                 p += m.kv_lora_rank * H * (m.qk_nope_head_dim + m.v_head_dim)
                 p += H * m.v_head_dim * d                               # o
@@ -172,10 +176,10 @@ class ArchConfig:
                 if self.moe_on_layer(i):
                     m = self.moe
                     eff = m.d_ff_expert or dff
-                    layer_t += m.n_experts * ffn_params(eff) / 3 * 3
+                    layer_t += m.n_experts * ffn_params(eff)
                     layer_a += m.top_k * ffn_params(eff)
-                    if m.shared_expert:
-                        layer_t += ffn_params(eff); layer_a += ffn_params(eff)
+                    layer_t += m.n_shared * ffn_params(eff)
+                    layer_a += m.n_shared * ffn_params(eff)
                 else:
                     layer_t += ffn_params(dff); layer_a += ffn_params(dff)
             total += layer_t; active += layer_a
@@ -226,6 +230,7 @@ ARCH_IDS = (
     "jamba-v0.1-52b", "whisper-medium", "xlstm-1.3b",
     # paper's own workloads:
     "llama3.1-8b", "smolvlm",
+    "deepseek-v2-lite",
 )
 
 _MOD = {a: a.replace("-", "_").replace(".", "_") for a in ARCH_IDS}

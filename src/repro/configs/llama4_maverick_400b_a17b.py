@@ -7,12 +7,12 @@ from repro.configs.base import ArchConfig, MoEConfig
 CONFIG = ArchConfig(
     name="llama4-maverick-400b-a17b", family="moe", n_layers=48, d_model=5120,
     n_heads=40, n_kv_heads=8, d_ff=8192, vocab=202048, rope_theta=500000.0,
-    moe=MoEConfig(n_experts=128, top_k=1, every=2, shared_expert=True),
+    moe=MoEConfig(n_experts=128, top_k=1, every=2, n_shared=1),
 )
 
 def reduced() -> ArchConfig:
     return ArchConfig(
         name="llama4-maverick-reduced", family="moe", n_layers=2, d_model=64,
         n_heads=4, n_kv_heads=2, d_ff=128, vocab=256,
-        moe=MoEConfig(n_experts=4, top_k=1, every=2, shared_expert=True),
+        moe=MoEConfig(n_experts=4, top_k=1, every=2, n_shared=1),
     )

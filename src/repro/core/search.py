@@ -33,7 +33,9 @@ from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.ppa import config_space as cs
 from repro.ppa import surrogate as sur_mod
-from repro.ppa.analytic import M_DIM, M_IDX, evaluate_batch, evaluate_vec_jit
+from repro.ppa.analytic import (INFEASIBLE_REASONS, M_DIM, M_IDX,
+                                evaluate_batch, evaluate_vec_jit,
+                                infeasible_counts)
 from repro.workload.features import Workload
 
 
@@ -543,6 +545,9 @@ def _search_cells(workload, node_nms, *, high_perf, search, lanes_per_cell,
     _m_kept = _reg.counter("pareto_kept_total")
     _m_front = _reg.gauge("pareto_frontier_max")
     _m_disp = _reg.histogram("dispatch_seconds")
+    _m_infeasible = {r: _reg.counter("env_infeasible_total",
+                                     labels={"reason": r})
+                     for r in INFEASIBLE_REASONS}
     # act, env_step, archive and learn cover a dispatch_seconds interval;
     # telemetry follows it, inside the dispatch's annotation
     _m_phase = {p: _reg.histogram("search_phase_seconds",
@@ -768,6 +773,8 @@ def _search_cells(workload, node_nms, *, high_perf, search, lanes_per_cell,
                 _prev_off = _off
                 _m_kept.inc(kept)
                 _m_front.set(max(len(a) for a in archives))
+                for _r, _n in infeasible_counts(info.metrics).items():
+                    _m_infeasible[_r].inc(_n)
                 if t == start_t:
                     obs_trace.complete("first_dispatch", _dt0, _td,
                                        cat="search", cells=n_cells,

@@ -78,7 +78,8 @@ def param_spec(path: str, shape: Tuple[int, ...], mesh: Mesh,
     while weight gathers would be ~GB (the §Perf decode hillclimb)."""
     parts = path.split("/")
     name = parts[-2] if parts[-1] in ("w", "b") else parts[-1]
-    stacked = parts[0] == "blocks" or (len(parts) > 1 and parts[1] == "blocks")
+    stacked = parts[0] in ("blocks", "lead") or (
+        len(parts) > 1 and parts[1] in ("blocks", "lead"))
     lead = (None,) if stacked else ()
     row_in = fsdp if not serve else None     # contracting-dim shard (train)
 

@@ -57,10 +57,14 @@ def block_init(key, cfg: ArchConfig, kind: str, moe_on: bool) -> Dict:
         if cfg.mla is not None:
             m = cfg.mla
             qk_d = m.qk_nope_head_dim + m.qk_rope_head_dim
+            if m.q_lora_rank is None:
+                p.update(wq=L.linear_init(ks[0], d, H * qk_d, dt))
+            else:
+                p.update(
+                    wdq=L.linear_init(ks[0], d, m.q_lora_rank, dt),
+                    q_norm=L.rmsnorm_init(m.q_lora_rank, dt),
+                    wuq=L.linear_init(ks[1], m.q_lora_rank, H * qk_d, dt))
             p.update(
-                wdq=L.linear_init(ks[0], d, m.q_lora_rank, dt),
-                q_norm=L.rmsnorm_init(m.q_lora_rank, dt),
-                wuq=L.linear_init(ks[1], m.q_lora_rank, H * qk_d, dt),
                 wdkv=L.linear_init(ks[2], d,
                                    m.kv_lora_rank + m.qk_rope_head_dim, dt),
                 kv_norm=L.rmsnorm_init(m.kv_lora_rank, dt),
@@ -138,10 +142,13 @@ def block_init(key, cfg: ArchConfig, kind: str, moe_on: bool) -> Dict:
                            * (1.0 / np.sqrt(eff))).astype(dt)
             if p["e_gate"] is None:
                 del p["e_gate"]
-            if m.shared_expert:
-                p["s_gate"] = L.linear_init(ks[13], d, eff, dt)
-                p["s_up"] = L.linear_init(ks[14], d, eff, dt)
-                p["s_down"] = L.linear_init(ks[15], eff, d, dt)
+            if m.n_shared:
+                # the shared experts as one SwiGLU of n_shared * eff
+                # (DeepSeek-V2 and Llama 4 build them so)
+                sf = m.n_shared * eff
+                p["s_gate"] = L.linear_init(ks[13], d, sf, dt)
+                p["s_up"] = L.linear_init(ks[14], d, sf, dt)
+                p["s_down"] = L.linear_init(ks[15], sf, d, dt)
         else:
             if cfg.mlp_gated:
                 p["w_gate"] = L.linear_init(ks[9], d, cfg.d_ff, dt)
@@ -283,15 +290,22 @@ def _moe(p: Dict, cfg: ArchConfig, h: jnp.ndarray) -> jnp.ndarray:
 # ===========================================================================
 # attention blocks (full sequence)
 # ===========================================================================
+def _mla_q(p: Dict, cfg: ArchConfig, h: jnp.ndarray) -> jnp.ndarray:
+    """MLA queries [..., H*qk_d]: through the q LoRA (down, norm, up), or
+    one projection where the arch has none."""
+    if cfg.mla.q_lora_rank is None:
+        return L.linear(p["wq"], h)
+    return L.linear(p["wuq"], L.rmsnorm(p["q_norm"], L.linear(p["wdq"], h),
+                                        cfg.norm_eps))
+
+
 def _attn_qkv(p: Dict, cfg: ArchConfig, h: jnp.ndarray, positions):
     B, S, d = h.shape
     hd, H, Hk = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     if cfg.mla is not None:
         m = cfg.mla
         qk_d = m.qk_nope_head_dim + m.qk_rope_head_dim
-        q = L.linear(p["wuq"], L.rmsnorm(p["q_norm"], L.linear(p["wdq"], h),
-                                         cfg.norm_eps))
-        q = q.reshape(B, S, H, qk_d)
+        q = _mla_q(p, cfg, h).reshape(B, S, H, qk_d)
         ckv = L.linear(p["wdkv"], h)
         c, k_rope = jnp.split(ckv, [m.kv_lora_rank], axis=-1)
         c = L.rmsnorm(p["kv_norm"], c, cfg.norm_eps)
@@ -599,10 +613,7 @@ def block_decode(params: Dict, cfg: ArchConfig, kind: str, moe_on: bool,
             m = cfg.mla
             r = m.kv_lora_rank
             qk_d = m.qk_nope_head_dim + m.qk_rope_head_dim
-            q = L.linear(params["wuq"],
-                         L.rmsnorm(params["q_norm"],
-                                   L.linear(params["wdq"], h), cfg.norm_eps))
-            q = q.reshape(B, 1, H, qk_d)
+            q = _mla_q(params, cfg, h).reshape(B, 1, H, qk_d)
             q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
             q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta)
             ckv_t = L.linear(params["wdkv"], h)            # [B,1,r+rope]

@@ -10,6 +10,11 @@ O(depth), which keeps 512-device dry-run compiles fast (DESIGN.md §7).
 Whisper (enc-dec) runs an encoder scan over the (stub) frame embeddings and
 gives every decoder layer a cross-attention block ("xattn" kinds).
 
+Leading layers outside the pattern (DeepSeek-V2's ``first_k_dense`` dense
+layers before the MoE stack) run before the scan, one block each, under
+``params["lead"]["l<i>"]`` and cache ``l<i>``, stacked over one period so
+that they share the scanned blocks' layout.
+
 Public entry points:
   init_params(key, cfg)
   forward(params, cfg, tokens, ctx=None)            -> logits
@@ -38,6 +43,11 @@ def decoder_kinds(cfg: ArchConfig) -> Tuple[str, ...]:
     return cfg.layer_kinds()
 
 
+def lead_of(cfg: ArchConfig) -> int:
+    """Leading layers that run before the repeating pattern."""
+    return cfg.moe.first_k_dense if cfg.moe is not None else 0
+
+
 def period_of(cfg: ArchConfig) -> int:
     if cfg.is_encdec:
         return 1
@@ -52,19 +62,34 @@ def period_of(cfg: ArchConfig) -> int:
     if cfg.moe is not None and cfg.moe.every > 1:
         import math
         p = p * cfg.moe.every // math.gcd(p, cfg.moe.every)
-    return p if cfg.n_layers % p == 0 else cfg.n_layers
+    n = cfg.n_layers - lead_of(cfg)
+    return p if n % p == 0 else n
 
 
 def _layout(cfg: ArchConfig) -> Tuple[int, int, List[Tuple[str, bool]]]:
+    """(period, periods, slots) of the layers after the leading ones."""
     kinds = decoder_kinds(cfg)
-    p = period_of(cfg)
-    n_periods = cfg.n_layers // p
-    slots = [(kinds[j], cfg.moe_on_layer(j)) for j in range(p)]
+    lead, p = lead_of(cfg), period_of(cfg)
+    n_periods = (cfg.n_layers - lead) // p
+    slots = [(kinds[lead + j], cfg.moe_on_layer(lead + j)) for j in range(p)]
     # verify the pattern really repeats
-    for i in range(cfg.n_layers):
-        assert kinds[i] == slots[i % p][0], (cfg.name, i)
-        assert cfg.moe_on_layer(i) == slots[i % p][1], (cfg.name, i)
+    for i in range(lead, cfg.n_layers):
+        assert kinds[i] == slots[(i - lead) % p][0], (cfg.name, i)
+        assert cfg.moe_on_layer(i) == slots[(i - lead) % p][1], (cfg.name, i)
     return p, n_periods, slots
+
+
+def _lead_slots(cfg: ArchConfig) -> List[Tuple[str, bool]]:
+    kinds = decoder_kinds(cfg)
+    return [(kinds[i], cfg.moe_on_layer(i)) for i in range(lead_of(cfg))]
+
+
+def _unstack(tree):
+    return jax.tree.map(lambda a: a[0], tree)
+
+
+def _stack(tree):
+    return jax.tree.map(lambda a: a[None], tree)
 
 
 # ------------------------------------------------------------------- init
@@ -79,6 +104,12 @@ def init_params(key, cfg: ArchConfig) -> Dict:
         blocks[f"p{j}"] = jax.vmap(
             lambda k: blk.block_init(k, cfg, kind, moe_on))(ks)
     params["blocks"] = blocks
+    lead = _lead_slots(cfg)
+    if lead:
+        params["lead"] = {
+            f"l{i}": jax.vmap(lambda k: blk.block_init(k, cfg, kind, moe_on))(
+                jax.random.split(jax.random.fold_in(keys[5], i), 1))
+            for i, (kind, moe_on) in enumerate(lead)}
     params["final_norm"] = L.rmsnorm_init(cfg.d_model, dt)
     if not cfg.tie_embeddings:
         params["lm_head"] = L.linear_init(keys[2], cfg.d_model, cfg.vocab, dt)
@@ -129,6 +160,14 @@ def forward(params: Dict, cfg: ArchConfig, tokens: jnp.ndarray,
     x = _embed_inputs(params, cfg, tokens, ctx)
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+    lead_caches = {}
+    for i, (kind, moe_on) in enumerate(_lead_slots(cfg)):
+        x, c = blk.block_apply(_unstack(params["lead"][f"l{i}"]), cfg, kind,
+                               moe_on, x, ctx=ctx, positions=positions,
+                               collect_cache=collect_caches)
+        x = x.astype(L.dtype_of(cfg.param_dtype))
+        if collect_caches:
+            lead_caches[f"l{i}"] = _stack(c)
 
     def body(x, period_params):
         # re-pin the scan carry's sharding: without this Shardy may leave
@@ -151,6 +190,7 @@ def forward(params: Dict, cfg: ArchConfig, tokens: jnp.ndarray,
     # whole saved-carry stack (~5 GiB/device at 70B scale, §Perf)
     x, caches = jax.lax.scan(
         jax.checkpoint(body, prevent_cse=False), x, params["blocks"])
+    caches = {**lead_caches, **caches}
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if return_hidden:
         return (x, caches) if collect_caches else x
@@ -207,8 +247,13 @@ def init_caches(cfg: ArchConfig, batch: int, cache_len: int) -> Dict:
         return jax.tree.map(
             lambda a: jnp.broadcast_to(a[None], (n_periods,) + a.shape), tree)
 
-    return {f"p{j}": stack(blk.init_cache(cfg, kind, batch, cache_len, dt))
-            for j, (kind, _) in enumerate(slots)}
+    caches = {f"l{i}": _stack(blk.init_cache(cfg, kind, batch, cache_len,
+                                             dt))
+              for i, (kind, _) in enumerate(_lead_slots(cfg))}
+    caches.update({f"p{j}": stack(blk.init_cache(cfg, kind, batch, cache_len,
+                                                 dt))
+                   for j, (kind, _) in enumerate(slots)})
+    return caches
 
 
 def prefill(params: Dict, cfg: ArchConfig, tokens: jnp.ndarray,
@@ -293,6 +338,13 @@ def decode_step(params: Dict, cfg: ArchConfig, token: jnp.ndarray,
     del ctx
     ctx = None
     x = L.embed(params["embed"], token)
+    lead_caches = {}
+    for i, (kind, moe_on) in enumerate(_lead_slots(cfg)):
+        x, c = blk.block_decode(_unstack(params["lead"][f"l{i}"]), cfg, kind,
+                                moe_on, x, _unstack(caches[f"l{i}"]), pos,
+                                ctx=ctx)
+        x = x.astype(L.dtype_of(cfg.param_dtype))
+        lead_caches[f"l{i}"] = _stack(c)
 
     def body(x, scanned):
         period_params, period_cache = scanned
@@ -303,7 +355,10 @@ def decode_step(params: Dict, cfg: ArchConfig, token: jnp.ndarray,
             new_cache[f"p{j}"] = c
         return x.astype(L.dtype_of(cfg.param_dtype)), new_cache
 
-    x, new_caches = jax.lax.scan(body, x, (params["blocks"], caches))
+    x, new_caches = jax.lax.scan(
+        body, x, (params["blocks"],
+                  {k: v for k, v in caches.items() if k not in lead_caches}))
+    new_caches = {**lead_caches, **new_caches}
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if cfg.tie_embeddings:
         logits = x @ params["embed"]["w"].T

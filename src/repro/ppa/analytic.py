@@ -57,7 +57,7 @@ METRICS = [
     "bisect_bytes_s", "hbar", "eta_par", "noc_latency_cyc",
     "p_compute_mw", "p_sram_mw", "p_rom_mw", "p_noc_mw", "p_leak_mw",
     "util", "kv_total_mb", "kappa_compact", "xtile_bytes_tok",
-    "n_cores", "f_hz", "load_balance",
+    "n_cores", "f_hz", "load_balance", "scratch_ok",
 ]
 M_IDX = {n: i for i, n in enumerate(METRICS)}
 M_DIM = len(METRICS)
@@ -200,9 +200,9 @@ def evaluate(cfg: jnp.ndarray, wl: jnp.ndarray, node: jnp.ndarray) -> jnp.ndarra
         0.0, 1.0 - _g(cfg, "dmem_in_frac") - _g(cfg, "dmem_out_frac"))
     scratch_need_kb = _w(wl, "d_model") * 2.0 * 2.0 / 1024.0
     kv_per_tile_kb = kv_total_mb * 1024.0 / n_cores
+    scratch_ok = dmem_scr_kb >= scratch_need_kb                         # Eq. 28
     dmem_ok = jnp.logical_and(
-        kv_spill_mb <= wmem_headroom_mb,                                 # Eq. 27
-        dmem_scr_kb >= scratch_need_kb)                                  # Eq. 28
+        kv_spill_mb <= wmem_headroom_mb, scratch_ok)                     # Eq. 27
     power_ok = power_mw <= _n(node, "power_budget_mw")
     area_ok = area <= _n(node, "area_budget_mm2")
     feasible = (wmem_ok & dmem_ok & power_ok & area_ok).astype(jnp.float32)
@@ -247,11 +247,32 @@ def evaluate(cfg: jnp.ndarray, wl: jnp.ndarray, node: jnp.ndarray) -> jnp.ndarra
         p_noc_mw=p_noc, p_leak_mw=p_leak, util=util,
         kv_total_mb=kv_total_mb, kappa_compact=kappa,
         xtile_bytes_tok=xtile_tok, n_cores=n_cores, f_hz=f,
-        load_balance=load_balance,
+        load_balance=load_balance, scratch_ok=scratch_ok,
     )
     for k, v in vals.items():
         out = out.at[M_IDX[k]].set(v.astype(jnp.float32))
     return out
+
+
+# constraints a design can break, as ``env_infeasible_total{reason}`` counts
+INFEASIBLE_REASONS = ("wmem", "dmem", "power", "area", "kv_spill")
+
+
+def infeasible_counts(metrics: np.ndarray) -> Dict[str, int]:
+    """Designs of a batch that break each constraint, from its metrics rows
+    (already on the host): WMEM capacity (Eq. 14), the DMEM scratch
+    partition (Eq. 28), the KV spill over the WMEM headroom (Eq. 27:
+    ``dmem_ok`` broken with the scratch partition met), and the power and
+    area budgets.  A design may break several."""
+    broken = {k: metrics[:, M_IDX[k]] < 0.5
+              for k in ("wmem_ok", "dmem_ok", "scratch_ok", "power_ok",
+                        "area_ok")}
+    return dict(wmem=int(broken["wmem_ok"].sum()),
+                dmem=int(broken["scratch_ok"].sum()),
+                power=int(broken["power_ok"].sum()),
+                area=int(broken["area_ok"].sum()),
+                kv_spill=int((broken["dmem_ok"]
+                              & ~broken["scratch_ok"]).sum()))
 
 
 def score_weights(high_perf):

@@ -6,6 +6,14 @@ instantiates the JAX model, so the DSE plane and the workload plane share one
 source of truth (DESIGN.md §2).  Granularity is one op per logical tensor
 operation (the paper's ONNX granularity is finer; op *counts* therefore
 differ from Table 8 while all flop/byte aggregates match analytically).
+
+MoE layers hold one grouped op for the routed experts, one op per shared
+expert (``MoEConfig.n_shared``) and a combine over the ``top_k + n_shared``
+outputs; the ``first_k_dense`` leading layers keep a dense ``d_ff`` FFN.
+Decode streams the weights outside the routed experts plus the top-k
+routed experts of every MoE layer; prefill streams them all.  MLA without
+a q LoRA (``q_lora_rank=None``) has one ``q_proj`` in place of
+``q_down``/``q_up``.
 """
 from __future__ import annotations
 
@@ -16,6 +24,7 @@ from typing import List, Tuple
 import numpy as np
 
 from repro.configs.base import ArchConfig, MambaConfig, XLSTMConfig
+from repro.obs import trace as obs_trace
 from repro.workload.features import (KIND_ATTENTION, KIND_CONV, KIND_ELEMWISE,
                                      KIND_EMBED, KIND_MATMUL, KIND_NORM,
                                      KIND_ROUTE, KIND_SCAN, WL_IDX, Workload,
@@ -34,7 +43,7 @@ def routing_imbalance(n_experts: int, top_k: int, tokens: float) -> float:
     """Expected per-tile expert load imbalance from top-k routing.
 
     With ``tokens`` tokens routed independently to ``top_k`` of ``n_experts``
-    experts, each expert's load is Binomial(tokens*top_k, 1/n_experts); the
+    experts, each expert's load is Binomial(tokens, top_k/n_experts); the
     expected max-over-experts excess over the mean (Gumbel tail of n_experts
     normals) is ``sigma_rel * sqrt(2 ln n_experts)`` relative std, capped at
     the all-on-one-expert worst case ``n_experts/top_k - 1``.  Decode
@@ -117,8 +126,11 @@ def build_graph(cfg: ArchConfig, seq_len: int,
             if cfg.mla is not None:
                 m = cfg.mla
                 qk_d = m.qk_nope_head_dim + m.qk_rope_head_dim
-                qd = mm(f"L{li}.q_down", li, n0, d, m.q_lora_rank)
-                qu = mm(f"L{li}.q_up", li, qd, m.q_lora_rank, H * qk_d)
+                if m.q_lora_rank is None:
+                    qu = mm(f"L{li}.q_proj", li, n0, d, H * qk_d)
+                else:
+                    qd = mm(f"L{li}.q_down", li, n0, d, m.q_lora_rank)
+                    qu = mm(f"L{li}.q_up", li, qd, m.q_lora_rank, H * qk_d)
                 kv = mm(f"L{li}.kv_down", li, n0, d, m.kv_lora_rank + m.qk_rope_head_dim)
                 ku = mm(f"L{li}.kv_up", li, kv, m.kv_lora_rank,
                         H * (m.qk_nope_head_dim + m.v_head_dim))
@@ -178,11 +190,12 @@ def build_graph(cfg: ArchConfig, seq_len: int,
                               n_mats * 2.0 * d * eff * m.top_k,
                               by * n_mats * d * eff * m.n_experts,
                               ab * d * m.top_k, li, (rt,))]
-                if m.shared_expert:
-                    outs.append(g.add(f"L{li}.shared_exp", KIND_MATMUL,
+                for j in range(m.n_shared):
+                    outs.append(g.add(f"L{li}.shared_exp" + (f"_{j}" if j else ""),
+                                      KIND_MATMUL,
                                       n_mats * 2.0 * d * eff, by * n_mats * d * eff,
                                       ab * d, li, (n1,)))
-                n_act = m.top_k + (1 if m.shared_expert else 0)
+                n_act = m.top_k + m.n_shared
                 prev = g.add(f"L{li}.moe_combine", KIND_ELEMWISE, d * n_act, 0.0,
                              ab * d, li, tuple(outs))
             else:
@@ -212,6 +225,22 @@ def extract(cfg: ArchConfig, *, seq_len: int = 2048, batch: int = 1,
         raise ValueError(f"phase must be one of {PHASES}, got {phase!r}")
     if dtype not in DTYPES:
         raise ValueError(f"dtype must be one of {DTYPES}, got {dtype!r}")
+    with obs_trace.span("extract", cat="workload", arch=cfg.name,
+                        phase=phase, dtype=dtype) as sp:
+        graph, fields = workload_fields(cfg, seq_len=seq_len, batch=batch,
+                                        phase=phase, dtype=dtype)
+        sp.set(n_ops=graph.n_ops,
+               moe_layers=sum(cfg.moe_on_layer(li)
+                              for li in range(cfg.n_layers)))
+    return Workload(arch_name=cfg.name, features=wl_vector(**fields),
+                    graph=graph)
+
+
+def workload_fields(cfg: ArchConfig, *, seq_len: int, batch: int,
+                    phase: str, dtype: str) -> Tuple[WorkloadGraph, dict]:
+    """The operator graph and the workload features in float64, keyed by
+    ``WL_FIELDS`` name, before ``extract`` rounds them into the float32
+    vector."""
     if dtype != "native":
         cfg = dataclasses.replace(cfg, param_dtype=_DTYPE_PARAM[dtype])
     graph = build_graph(cfg, seq_len, phase)
@@ -232,7 +261,9 @@ def extract(cfg: ArchConfig, *, seq_len: int = 2048, batch: int = 1,
         # traffic select stays bitwise identical
         weight_traffic = weight_bytes
     else:
-        weight_traffic = pc["active"] * by  # only routed experts stream
+        # the top-k routed experts, the shared experts and every weight
+        # outside the experts (dense layers included) stream
+        weight_traffic = pc["active"] * by
 
     total_flops = float(graph.flops.sum())
     k_flops = graph.flops
@@ -258,7 +289,7 @@ def extract(cfg: ArchConfig, *, seq_len: int = 2048, batch: int = 1,
     fan = np.bincount(graph.edges[:, 0], minlength=graph.n_ops) if graph.edges.size else np.zeros(graph.n_ops)
     ilp = float(np.clip(fan.mean() / 2.0, 0.05, 1.0))
 
-    feats = wl_vector(
+    return graph, dict(
         params_total=pc["total"], params_active=pc["active"],
         weight_mb=weight_bytes / 1e6,
         flops_per_token=total_flops,
@@ -288,4 +319,3 @@ def extract(cfg: ArchConfig, *, seq_len: int = 2048, batch: int = 1,
         dtype_fp8=1.0 if dtype == "fp8" else 0.0,
         dtype_int8=1.0 if dtype == "int8" else 0.0,
     )
-    return Workload(arch_name=cfg.name, features=feats, graph=graph)

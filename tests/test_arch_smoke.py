@@ -11,9 +11,10 @@ from repro.models import layers as L
 from repro.models import lm
 from repro.optim.trainer import TrainConfig, create_state, make_train_step
 
-# tier-1 runs a small dense + MoE representative pair; the full zoo rides in
-# the slow tier (same assertions, just heavier reduced configs)
-FAST_ARCHS = ("smollm-135m", "mixtral-8x7b")
+# tier-1 runs a small dense + MoE representative pair and the MoE + MLA
+# arch with a leading dense layer; the full zoo rides in the slow tier
+# (same assertions, just heavier reduced configs)
+FAST_ARCHS = ("smollm-135m", "mixtral-8x7b", "deepseek-v2-lite")
 ASSIGNED = [a if a in FAST_ARCHS else pytest.param(a, marks=pytest.mark.slow)
             for a in ARCH_IDS]
 

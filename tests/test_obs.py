@@ -502,3 +502,76 @@ def test_tagged_meta_reaches_every_annotation_of_the_thread(tmp_path):
     assert stats["repro.request"] == {"req": 7}
     assert stats["repro.inner"] == {"req": 7, "step_num": 1}
     assert stats["repro.untagged"] == {}
+
+
+# ------------------------------------------- extraction and feasibility
+def test_extract_span_carries_the_scenario_and_graph_size(tmp_path):
+    path = str(tmp_path / obs_trace.TRACE_NAME)
+    tracer = obs_trace.Tracer(path, proc="t")
+    prev = obs_trace.install_tracer(tracer)
+    try:
+        wl = extract(get_config("deepseek-v2-lite"), seq_len=512, batch=8,
+                     phase="prefill", dtype="fp8")
+    finally:
+        obs_trace.install_tracer(prev)
+        tracer.close()
+    (rec,) = [r for r in obs_trace.read_trace(path)
+              if r.get("name") == "extract"]
+    assert rec["cat"] == "workload" and rec["dur"] >= 0
+    assert rec["args"] == dict(arch="deepseek-v2-lite", phase="prefill",
+                               dtype="fp8", n_ops=wl.graph.n_ops,
+                               moe_layers=26)
+
+
+def test_infeasible_counter_recounts_from_the_dispatch_rows(monkeypatch):
+    from repro.core import env as env_mod
+    from repro.ppa import config_space as cs
+    from repro.ppa.analytic import INFEASIBLE_REASONS, M_IDX
+    rows = []
+    original = env_mod.VecDSEEnv.step
+
+    def step(self, a_cont, a_disc):
+        out = original(self, a_cont, a_disc)
+        rows.append((out[2].metrics.copy(), out[2].cfg.copy()))
+        return out
+    monkeypatch.setattr(env_mod.VecDSEEnv, "step", step)
+    wl = extract(get_config("deepseek-v2-lite"), seq_len=4096, batch=8)
+    reg = obs_metrics.global_registry()
+    reg.clear()
+    run_search_cells(wl, [3, 28], lanes_per_cell=4,
+                     search=SearchConfig(episodes=64, warmup=24,
+                                         batch_size=32, seed=0))
+    snap = reg.snapshot()
+    got = {r: obs_metrics.snapshot_value(snap, "counters",
+                                         "env_infeasible_total",
+                                         {"reason": r})
+           for r in INFEASIBLE_REASONS}
+    m = np.concatenate([r[0] for r in rows])
+    cfg = np.concatenate([r[1] for r in rows]).astype(np.float64)
+    broken = {k: int((m[:, M_IDX[k]] < 0.5).sum())
+              for k in ("wmem_ok", "dmem_ok", "power_ok", "area_ok")}
+    scratch = cfg[:, cs.IDX["dmem_kb"]] * np.maximum(
+        0.0, 1.0 - cfg[:, cs.IDX["dmem_in_frac"]]
+        - cfg[:, cs.IDX["dmem_out_frac"]])
+    assert len(m) == 16 * 8
+    assert got["wmem"] == broken["wmem_ok"] > 0
+    assert got["power"] == broken["power_ok"]
+    assert got["area"] == broken["area_ok"]
+    assert got["dmem"] == int((scratch < 2048 * 4 / 1024).sum())
+    assert got["dmem"] + got["kv_spill"] == broken["dmem_ok"]
+
+
+def test_wmem_reject_share_reads_the_window_counters():
+    from types import SimpleNamespace
+
+    from bench import run as brun
+    read = brun.reader("wmem_reject_share")
+    counters = {("env_infeasible_total", (("reason", "wmem"),)): 300.0,
+                ("env_infeasible_total", (("reason", "area"),)): 50.0,
+                ("env_steps_total", ()): 1200.0}
+    run = SimpleNamespace(registry=dict(counters=counters, histograms={}))
+    assert read(run) == 25.0
+    del counters[("env_infeasible_total", (("reason", "wmem"),))]
+    assert read(run) is None
+    run.registry["counters"] = {("env_steps_total", ()): 0.0}
+    assert read(run) is None
