@@ -202,3 +202,48 @@ class ParetoArchive:
 
     def __len__(self) -> int:
         return len(self.entries)
+
+
+# an entry's metric fields, as the columns of :func:`pack_archives`
+METRIC_FIELDS = ("power_mw", "perf_gops", "area_mm2", "tok_s", "ppa_score")
+
+
+def pack_archives(archives: Sequence[ParetoArchive], cfg_dim: int
+                  ) -> tuple[Dict[str, np.ndarray], List[Dict]]:
+    """Several archives as columns and per-archive scalars.
+
+    The columns hold one row per entry, archive after archive, each in
+    entry order: ``cfg`` float32, the metric fields float64, ``episode``
+    int64 and ``cell``, the index of the row's archive.  The scalars are
+    each archive's ``max_size`` and ``n_inserted``.  These are the values
+    :meth:`ParetoArchive.from_dict` restores from :meth:`ParetoArchive.
+    to_dict`, so :func:`unpack_archives` gives the same archives bit for
+    bit."""
+    entries = [e for a in archives for e in a.entries]
+    cols = {f: np.array([getattr(e, f) for e in entries], np.float64)
+            for f in METRIC_FIELDS}
+    cols["cfg"] = (np.stack([np.asarray(e.cfg, np.float32) for e in entries])
+                   if entries else np.zeros((0, cfg_dim), np.float32))
+    cols["episode"] = np.array([e.episode for e in entries], np.int64)
+    cols["cell"] = np.repeat(np.arange(len(archives), dtype=np.int64),
+                             [len(a) for a in archives])
+    meta = [dict(max_size=a.max_size, n_inserted=a.n_inserted)
+            for a in archives]
+    return cols, meta
+
+
+def unpack_archives(cols: Dict[str, np.ndarray], meta: Sequence[Dict]
+                    ) -> List[ParetoArchive]:
+    """Exact inverse of :func:`pack_archives`."""
+    bounds = np.searchsorted(cols["cell"], np.arange(len(meta) + 1))
+    values = {f: cols[f].tolist() for f in METRIC_FIELDS + ("episode",)}
+    out = []
+    for c, m in enumerate(meta):
+        ar = ParetoArchive(max_size=int(m["max_size"]))
+        ar.entries = [
+            ArchiveEntry(cfg=np.array(cols["cfg"][i], np.float32),
+                         **{f: v[i] for f, v in values.items()})
+            for i in range(bounds[c], bounds[c + 1])]
+        ar.n_inserted = int(m["n_inserted"])
+        out.append(ar)
+    return out

@@ -8,6 +8,7 @@ implements the random-search and grid-search baselines of Table 21.
 from __future__ import annotations
 
 import dataclasses
+import os
 import time
 from collections import deque
 from typing import Dict, List, Optional, Sequence
@@ -24,7 +25,8 @@ from repro.core import world_model as wm_mod
 from repro.core.env import DSEEnv, VecDSEEnv
 from repro.core.exploration import EpsilonSchedule
 from repro.core.hetero import HeteroConfig, derive
-from repro.core.pareto import ArchiveEntry, ParetoArchive
+from repro.core.pareto import (ArchiveEntry, ParetoArchive, pack_archives,
+                               unpack_archives)
 from repro.core.partition import partition
 from repro.core.replay import PERBuffer
 from repro.core.state import SAC_STATE_DIM
@@ -80,6 +82,13 @@ class TracePoint:
     unique_configs: int
     feasible_count: int
     tok_s: float
+
+    @classmethod
+    def from_dict(cls, d: Dict) -> "TracePoint":
+        """Inverse of ``dataclasses.asdict`` after a JSON round trip, in
+        which a non-finite float came back as text ("inf")."""
+        return cls(**{f.name: (float if f.type == "float" else int)(
+            d[f.name]) for f in dataclasses.fields(cls)})
 
 
 @dataclasses.dataclass
@@ -283,6 +292,122 @@ def _save_search_ckpt(ckpt_dir: str, step: int, tree: Dict, extra: Dict,
     return ckpt_mod.save(tree, ckpt_dir, step, keep=keep, extra=extra)
 
 
+# what a search checkpoint writes of the PER buffer, one row a transition
+PER_FIELDS = ("s", "a_cont", "a_disc", "r", "s2", "done")
+# bucket edges of ``search_checkpoint_bytes`` (arrays.npz + manifest.json)
+CKPT_BYTES_EDGES = (1e5, 1e6, 3e6, 1e7, 3e7, 1e8, 3e8, 1e9)
+
+
+class SeenKeys:
+    """The distinct designs each cell has seen, as :func:`_cfg_key` values.
+
+    Per cell a set answers membership, and the same keys are kept as
+    float64 rows in first-seen order, so a checkpoint writes them as one
+    array and no key is converted again."""
+
+    def __init__(self, n_cells: int):
+        self.sets: List[set] = [set() for _ in range(n_cells)]
+        self._rows: List[List[np.ndarray]] = [[] for _ in range(n_cells)]
+
+    def add(self, cfg: np.ndarray, lanes: int) -> None:
+        """Record a dispatch's designs, ``lanes`` consecutive rows a cell."""
+        keys = np.round(np.asarray(cfg, np.float64), 3)
+        for c, s in enumerate(self.sets):
+            rows = keys[c * lanes:(c + 1) * lanes]
+            new = []
+            for i, k in enumerate(map(tuple, rows.tolist())):
+                if k not in s:
+                    s.add(k)
+                    new.append(i)
+            if new:
+                self._rows[c].append(rows[new])
+
+    def rows(self, c: int) -> np.ndarray:
+        """Cell ``c``'s keys in first-seen order, ``(n, DIM)`` float64."""
+        if len(self._rows[c]) != 1:
+            self._rows[c] = [np.concatenate(self._rows[c]) if self._rows[c]
+                             else np.zeros((0, cs.DIM))]
+        return self._rows[c][0]
+
+    def to_arrays(self) -> tuple:
+        """Every cell's keys, cell after cell, and each row's cell."""
+        rows = [self.rows(c) for c in range(len(self.sets))]
+        return (np.concatenate(rows),
+                np.repeat(np.arange(len(rows), dtype=np.int64),
+                          [len(r) for r in rows]))
+
+    @classmethod
+    def from_arrays(cls, keys: np.ndarray, cell: np.ndarray,
+                    n_cells: int) -> "SeenKeys":
+        out = cls(n_cells)
+        for c in range(n_cells):
+            rows = np.asarray(keys[cell == c], np.float64)
+            out.sets[c] = set(map(tuple, rows.tolist()))
+            out._rows[c] = [rows]
+        return out
+
+
+def _host_checkpoint(buf: PERBuffer, archives: List[ParetoArchive],
+                     seen: SeenKeys, traces: List[List[TracePoint]],
+                     best: List[tuple]) -> tuple:
+    """The search's record as a checkpoint writes it: ``(arrays,
+    manifest entries)``.
+
+    Sized to what the loop holds: the PER rows up to the buffer's fill
+    (the rows past it were never written, also once the ring wraps) with
+    the whole sum tree, the Pareto archives as columns
+    (:func:`pack_archives`), each cell's seen keys, incumbent and trace."""
+    n_cells = len(archives)
+    arrays = {f"per_{n}": getattr(buf, n)[:buf.size] for n in PER_FIELDS}
+    arrays["per_tree"] = buf.tree.tree
+    cols, meta = pack_archives(archives, cs.DIM)
+    arrays.update({f"archive_{k}": v for k, v in cols.items()})
+    arrays["seen_keys"], arrays["seen_cell"] = seen.to_arrays()
+    arrays["best_cfg"] = np.stack([
+        best[c][1] if best[c][1] is not None
+        else np.zeros(cs.DIM, np.float32) for c in range(n_cells)])
+    arrays["best_metrics"] = np.stack([
+        best[c][2] if best[c][2] is not None
+        else np.zeros(M_DIM, np.float32) for c in range(n_cells)])
+    extra = dict(
+        archive_meta=meta,
+        traces=[[dataclasses.asdict(tp) for tp in tr] for tr in traces],
+        best_has=[best[c][1] is not None for c in range(n_cells)],
+        best_score=[float(best[c][0]) for c in range(n_cells)])
+    return arrays, extra
+
+
+def _host_restore(flat: Dict[str, np.ndarray], ex: Dict,
+                  buf: PERBuffer) -> tuple:
+    """Inverse of :func:`_host_checkpoint` from a ``restore_flat`` dict
+    and the manifest's ``extra``: fills ``buf``'s rows and sum tree and
+    returns ``(archives, seen, traces, best)``.  Also reads the earlier
+    layout, with every PER row up to capacity and the archives as JSON
+    in the manifest."""
+    n_cells = len(ex["best_has"])
+    for n in PER_FIELDS:
+        arr = flat[f"host/per_{n}"]
+        getattr(buf, n)[:len(arr)] = arr
+    buf.tree.tree[...] = flat["host/per_tree"]
+    if "archives" in ex:
+        archives = [ParetoArchive.from_dict(d) for d in ex["archives"]]
+    else:
+        pre = "host/archive_"
+        archives = unpack_archives(
+            {k[len(pre):]: v for k, v in flat.items() if k.startswith(pre)},
+            ex["archive_meta"])
+    seen = SeenKeys.from_arrays(flat["host/seen_keys"],
+                                flat["host/seen_cell"], n_cells)
+    traces = [[TracePoint.from_dict(tp) for tp in tr] for tr in ex["traces"]]
+    best: List[tuple] = [(np.inf, None, None) for _ in range(n_cells)]
+    for c in range(n_cells):
+        if ex["best_has"][c]:
+            best[c] = (float(ex["best_score"][c]),
+                       flat["host/best_cfg"][c].copy(),
+                       flat["host/best_metrics"][c].copy())
+    return archives, seen, traces, best
+
+
 def run_search_cells(workload: Workload, node_nms: Sequence[int], *,
                      high_perf: bool = True,
                      search: Optional[SearchConfig] = None,
@@ -327,12 +452,12 @@ def run_search_cells(workload: Workload, node_nms: Sequence[int], *,
 
     Checkpoint/restore: with ``checkpoint_dir`` set and ``checkpoint_every
     > 0``, the complete loop state — SAC/world-model/surrogate parameters
-    and optimizers, PER buffer + sum-tree priorities, per-cell Pareto
-    archives and incumbents, epsilon schedule, Eq.-67 gate state
-    (per-cell residual variance, open episodes, screened/evaluated
-    counters) and every host/device RNG (including the dedicated screen
-    streams) — is atomically checkpointed every ``checkpoint_every``
-    dispatches.
+    and optimizers, PER buffer (its filled rows) + sum-tree priorities,
+    per-cell Pareto archives (as arrays) and incumbents, epsilon
+    schedule, Eq.-67 gate state (per-cell residual variance, open
+    episodes, screened/evaluated counters) and every host/device RNG
+    (including the dedicated screen streams) — is atomically checkpointed
+    every ``checkpoint_every`` dispatches.
     ``resume=True`` restarts from the latest checkpoint and is exact: a
     killed-and-resumed run reproduces the uninterrupted run bit-for-bit
     (test-enforced).
@@ -418,7 +543,7 @@ def _search_cells(workload, node_nms, *, high_perf, search, lanes_per_cell,
     screen_key = jax.random.PRNGKey(sc.seed + 7919)
     archives = [ParetoArchive() for _ in range(n_cells)]
     traces: List[List[TracePoint]] = [[] for _ in range(n_cells)]
-    seen: List[set] = [set() for _ in range(n_cells)]
+    seen = SeenKeys(n_cells)
     best: List[tuple] = [(np.inf, None, None) for _ in range(n_cells)]
     feasible_count = np.zeros(n_cells, np.int64)
     last_entropy = 0.0
@@ -467,9 +592,7 @@ def _search_cells(workload, node_nms, *, high_perf, search, lanes_per_cell,
         surrogate.resid_var = float(ex["sur_resid_var"])
         surrogate.n_updates = int(ex["sur_n_updates"])
         key = jnp.asarray(flat["device/key"])
-        for name in ("s", "a_cont", "a_disc", "r", "s2", "done"):
-            getattr(buf, name)[...] = flat[f"host/per_{name}"]
-        buf.tree.tree[...] = flat["host/per_tree"]
+        archives, seen, traces, best = _host_restore(flat, ex, buf)
         buf.pos, buf.size = int(ex["buf_pos"]), int(ex["buf_size"])
         buf.max_priority = float(ex["buf_max_priority"])
         buf.beta = float(ex["buf_beta"])
@@ -482,16 +605,6 @@ def _search_cells(workload, node_nms, *, high_perf, search, lanes_per_cell,
         for k in range(int(ex["sur_len"])):
             sur_x.append(flat["host/sur_x"][k])
             sur_y.append(flat["host/sur_y"][k])
-        archives = [ParetoArchive.from_dict(d) for d in ex["archives"]]
-        traces = [[TracePoint(**tp) for tp in tr] for tr in ex["traces"]]
-        seen = [set() for _ in range(n_cells)]
-        for row, c in zip(flat["host/seen_keys"], flat["host/seen_cell"]):
-            seen[int(c)].add(tuple(row.tolist()))
-        for c in range(n_cells):
-            if ex["best_has"][c]:
-                best[c] = (float(ex["best_score"][c]),
-                           flat["host/best_cfg"][c].copy(),
-                           flat["host/best_metrics"][c].copy())
         feasible_count = np.asarray(ex["feasible_count"], np.int64)
         no_improve = int(ex["no_improve"])
         last_entropy = float(ex["last_entropy"])
@@ -559,42 +672,34 @@ def _search_cells(workload, node_nms, *, high_perf, search, lanes_per_cell,
     _prev_scr = float(gate.screened.sum())
     _prev_ev = float(gate.evaluated.sum())
     _prev_off = sum(a.n_offered for a in archives)
+    _m_ckpt_bytes = _reg.histogram("search_checkpoint_bytes",
+                                   CKPT_BYTES_EDGES)
 
     def _checkpoint(t_next: int) -> None:
         with obs_trace.phase("checkpoint.gather"):
             tree, extra = _checkpoint_state()
-        _save_search_ckpt(checkpoint_dir, t_next, tree, extra)
+        final = _save_search_ckpt(checkpoint_dir, t_next, tree, extra)
+        _m_ckpt_bytes.observe(sum(
+            os.path.getsize(os.path.join(final, f))
+            for f in ("arrays.npz", "manifest.json")))
 
     def _checkpoint_state():
-        seen_keys = [k for c in range(n_cells) for k in seen[c]]
-        seen_cell = [c for c in range(n_cells) for _ in seen[c]]
         xdim = SAC_STATE_DIM + act.N_CONT
+        host, host_extra = _host_checkpoint(buf, archives, seen, traces,
+                                            best)
         tree = dict(
             device=dict(sac=sac_state, wm=wm_state,
                         sur_params=surrogate.params,
                         sur_opt=surrogate.opt_state, key=np.asarray(key),
                         screen_key=np.asarray(screen_key)),
             host=dict(
-                per_s=buf.s, per_a_cont=buf.a_cont, per_a_disc=buf.a_disc,
-                per_r=buf.r, per_s2=buf.s2, per_done=buf.done,
-                per_tree=buf.tree.tree,
+                host,
                 env_cfg=np.asarray(env.cfg), env_ranges=np.asarray(env.ranges),
                 obs=np.asarray(s),
                 sur_x=(np.stack(list(sur_x)) if sur_x
                        else np.zeros((0, b, xdim), np.float32)),
                 sur_y=(np.stack(list(sur_y)) if sur_y
-                       else np.zeros((0, b, 1), np.float32)),
-                seen_keys=(np.asarray(seen_keys, np.float64)
-                           if seen_keys else np.zeros((0, cs.DIM))),
-                seen_cell=np.asarray(seen_cell, np.int64),
-                best_cfg=np.stack([
-                    best[c][1] if best[c][1] is not None
-                    else np.zeros(cs.DIM, np.float32) for c in range(n_cells)]),
-                best_metrics=np.stack([
-                    best[c][2] if best[c][2] is not None
-                    else np.zeros(M_DIM, np.float32)
-                    for c in range(n_cells)]),
-            ))
+                       else np.zeros((0, b, 1), np.float32))))
         extra = dict(
             node_nms=[int(n) for n in node_nms], lanes=lanes,
             episodes=sc.episodes, high_perf=high_perf, seed=sc.seed,
@@ -605,16 +710,12 @@ def _search_cells(workload, node_nms, *, high_perf, search, lanes_per_cell,
             buf_max_priority=buf.max_priority, buf_beta=buf.beta,
             sur_resid_var=surrogate.resid_var,
             sur_n_updates=surrogate.n_updates, sur_len=len(sur_x),
-            archives=[a.to_dict() for a in archives],
-            traces=[[dataclasses.asdict(tp) for tp in tr] for tr in traces],
-            best_has=[best[c][1] is not None for c in range(n_cells)],
-            best_score=[float(best[c][0]) for c in range(n_cells)],
             feasible_count=feasible_count.tolist(), no_improve=no_improve,
             last_entropy=last_entropy, gate=gate.to_dict(),
             gate_cfg=dict(surrogate_gate=bool(sc.surrogate_gate),
                           screen_k=sc.screen_k,
                           gate_threshold=sc.gate_threshold),
-            screen_rng=screen_rng.bit_generator.state)
+            screen_rng=screen_rng.bit_generator.state, **host_extra)
         return tree, extra
 
     for t in range(start_t, n_steps):
@@ -702,8 +803,7 @@ def _search_cells(workload, node_nms, *, high_perf, search, lanes_per_cell,
                                        info.metrics[j].copy())
                             improved = True
                     feasible_count[c] += int(info.feasible[lo:hi].sum())
-                    for i in range(lo, hi):
-                        seen[c].add(_cfg_key(info.cfg[i]))
+                seen.add(info.cfg, lanes)
                 t_env += lanes
                 no_improve = 0 if improved else no_improve + lanes
                 # ---- gate accounting + online calibration (Eq. 66) ----
@@ -790,7 +890,7 @@ def _search_cells(workload, node_nms, *, high_perf, search, lanes_per_cell,
                             episode=t_env, reward=float(np.mean(r[lo:hi])),
                             best_score=float(best[c][0]), eps=eps_sched.eps,
                             entropy=last_entropy,
-                            unique_configs=len(seen[c]),
+                            unique_configs=len(seen.sets[c]),
                             feasible_count=int(feasible_count[c]),
                             tok_s=float(np.mean(
                                 info.metrics[lo:hi, M_IDX["tok_s"]]))))
@@ -883,7 +983,7 @@ def _search_cells(workload, node_nms, *, high_perf, search, lanes_per_cell,
                         if best_metrics is not None else float("inf")),
             archive=archives[c], trace=traces[c], hetero=hetero,
             episodes_run=t_env, feasible_count=int(feasible_count[c]),
-            unique_configs=len(seen[c]), wall_s=wall,
+            unique_configs=len(seen.sets[c]), wall_s=wall,
             gate_open_episode=(int(gate.open_at[c])
                                if gate.open_at[c] >= 0 else None),
             screened=int(gate.screened[c]),
